@@ -149,6 +149,17 @@ def fig7_performance(workloads=WL.WORKLOAD_NAMES, seeds=(0,),
     default event path byte-identically; ``"wavefront"`` reproduces the
     orderings within the documented tolerance, DESIGN.md §9)."""
     seeds = tuple(seeds)
+    return fig7_table(
+        lambda wl, pol, sd: float(_run(wl, pol, sd, seeds, engine)["ipc"]),
+        workloads, seeds)
+
+
+def fig7_table(ipc, workloads, seeds=(0,)):
+    """Fig 7's rows and derived numbers from ``ipc(workload, policy,
+    seed)``, whichever run produced it: speedup over Baseline per
+    workload (mean over seeds), the idealized Rand column, and the
+    harmonic means."""
+    seeds = tuple(seeds)
     policies = list(BL.ALL_NAMED)
     rows = []
     speedups: Dict[str, List[float]] = {p.name: [] for p in policies}
@@ -157,15 +168,13 @@ def fig7_performance(workloads=WL.WORKLOAD_NAMES, seeds=(0,),
         per_pol: Dict[str, List[float]] = {p.name: [] for p in policies}
         ideal: List[float] = []
         for sd in seeds:
-            base = float(_run(wl, BL.BASELINE, sd, seeds, engine)["ipc"])
+            base = ipc(wl, BL.BASELINE, sd)
             for pol in policies:
-                per_pol[pol.name].append(
-                    float(_run(wl, pol, sd, seeds, engine)["ipc"]) / base)
+                per_pol[pol.name].append(ipc(wl, pol, sd) / base)
             # idealized Rand: best bypass probability per workload
             # (paper fn.3)
-            ideal.append(max(
-                float(_run(wl, BL.rand(p), sd, seeds, engine)["ipc"]) / base
-                for p in (0.25, 0.5, 0.75)))
+            ideal.append(max(ipc(wl, BL.rand(p), sd) / base
+                             for p in (0.25, 0.5, 0.75)))
         for pol in policies:
             s = float(np.mean(per_pol[pol.name]))
             speedups[pol.name].append(s)

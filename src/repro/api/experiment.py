@@ -204,10 +204,12 @@ class Experiment:
     engine: str = "event"
     wave_size: Optional[int] = None
     #: wavefront timing-pass backend (repro.kernels.wavefront_scan);
-    #: "auto" = fused lax scans on CPU, Pallas kernel on TPU
+    #: "auto" = fused lax scans on every platform; "pallas" is an
+    #: opt-in that the TPU compiler refuses today
     scan_backend: str = "auto"
     #: wavefront cache-pass backend (repro.kernels.cache_pass);
-    #: "auto" = fused one-sweep on CPU, Pallas kernel on TPU
+    #: "auto" = fused one-sweep on every platform; "pallas" is an
+    #: opt-in that the TPU compiler refuses today
     cache_backend: str = "auto"
     #: serving-engine pool-transaction backend (engine="serving" only);
     #: "auto"/"fast" = vectorized access_batch, "ref" = sequential per-key

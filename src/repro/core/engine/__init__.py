@@ -213,9 +213,10 @@ def simulate(trace_lines, trace_pcs, compute_gap, *, n_warps: int,
     ``scan_backend`` selects the wavefront timing-pass implementation
     (``repro.kernels.wavefront_scan``) and ``cache_backend`` the
     cache-pass one (``repro.kernels.cache_pass``): ``"auto"`` (default)
-    picks the fused one-sweep path on CPU and the Pallas kernel on TPU,
-    both output-identical to ``"ref"``, the unfused pre-fusion form kept
-    for in-run perf A/Bs. The two knobs compose freely.
+    picks the fused one-sweep path on every platform, output-identical
+    to ``"ref"``, the unfused pre-fusion form kept for in-run perf A/Bs;
+    ``"pallas"`` is an opt-in the TPU compiler refuses today. The two
+    knobs compose freely.
 
     trace_lines: i32[I, W, L]; trace_pcs: i32[I, W]; compute_gap: f32
     scalar or f32[I] (phased per-instruction intensity); oracle_types:

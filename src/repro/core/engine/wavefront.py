@@ -22,7 +22,7 @@ passes:
      pass's ``scan_backend``): ``"ref"`` is the original per-lane
      ``lax.scan``, ``"fused"`` a bitwise-identical one-sweep
      reformulation that resolves same-set write conflicts with explicit
-     per-set chronology pointers (the CPU default), ``"pallas"`` a
+     per-set chronology pointers (the default), ``"pallas"`` a
      lane-chunked TPU kernel. The lifetime counters and scalar metrics
      — never read during the wave — are hoisted out of the pass and
      applied once per wave for every backend (integer adds, so the
@@ -37,7 +37,7 @@ passes:
      ``repro.kernels.wavefront_scan`` behind a backend gate
      (``scan_backend``): ``"ref"`` is the original unfused multi-pass
      form, ``"fused"`` a bitwise-identical slot-major reformulation with
-     fast associative scans (the CPU default), ``"pallas"`` a one-pass
+     fast associative scans (the default), ``"pallas"`` a one-pass
      TPU kernel. The DRAM row-buffer chain links each request to its
      true chronological predecessor in its channel, and the low-priority
      queue's floor folds in the running busy horizon of the wave's
@@ -119,6 +119,22 @@ def _replicate_constraint(mesh):
         return lambda x: x
     return lambda x: jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, PartitionSpec(*([None] * x.ndim))))
+
+
+def _fixed_order_sum(x):
+    """Sum of a 1-D f32 array in one fixed pairwise order, written out
+    as elementwise adds. ``jnp.sum`` leaves the order to the compiler,
+    which picks it per backend and per program (XLA:TPU differs from
+    XLA:CPU, and the warp-sharded program from the one-chip one); once
+    partial sums pass 2**24 the order shows in the last bits. Zero
+    padding to a power of two adds nothing."""
+    n = x.shape[0]
+    size = 1 << max(n - 1, 0).bit_length()
+    x = jnp.pad(x, (0, size - n))
+    while x.shape[0] > 1:
+        half = x.shape[0] // 2
+        x = x[:half] + x[half:]
+    return x[0]
 
 
 def default_wave_size(n_warps: int) -> int:
@@ -217,7 +233,7 @@ def _timing_pass(st: SimState, an: QueueAnchors, recs, prm: SimParams,
     else:
         metrics["qdelay_hist"] = m["qdelay_hist"].at[qbin].add(
             use_l2_s.astype(I32))
-    metrics["qdelay_sum"] = m["qdelay_sum"] + jnp.sum(qdelay)
+    metrics["qdelay_sum"] = m["qdelay_sum"] + _fixed_order_sum(qdelay)
     metrics["dram_accesses"] = m["dram_accesses"] + jnp.sum(
         go_dram.astype(I32))
     metrics["row_hits"] = m["row_hits"] + jnp.sum(row_hit.astype(I32))
@@ -247,7 +263,7 @@ def simulate_core(trace_lines, trace_pcs, compute_gap, oracle_types,
     ``cache_backend`` the cache-pass one (``cache_pass.BACKENDS``):
     ``"ref"`` is the respective pre-fusion path kept as the unfused side
     of the in-run perf A/B; every other backend is output-identical to
-    it (bitwise for ``"fused"``, the CPU default under ``"auto"``), so
+    it (bitwise for ``"fused"``, the default under ``"auto"``), so
     the two knobs compose freely.
 
     ``warp_mesh`` + ``warp_axes`` (both static, pre-resolved by the
@@ -358,7 +374,8 @@ def simulate_core(trace_lines, trace_pcs, compute_gap, oracle_types,
         has_req = jnp.isfinite(dmax)
         stall = jnp.where(has_req & slot_ok, dmax - dmin, 0.0)
         metrics = dict(st.metrics)
-        metrics["stall_cycles"] = metrics["stall_cycles"] + jnp.sum(stall)
+        metrics["stall_cycles"] = (metrics["stall_cycles"]
+                                   + _fixed_order_sum(stall))
         st = st._replace(metrics=metrics)
 
         w_ok = jnp.where(slot_ok, w_sel, n_warps)    # OOB -> dropped

@@ -12,10 +12,14 @@ the state exactly as the reference scan's lane sub-step does, parity
 with the ref/fused backends is structural — pinned bitwise by
 tests/test_kernels.py under ``interpret=True``.
 
-Caveat (shared with ``wavefront_scan``, tracked in ROADMAP): only
-interpreter mode is exercised in CI — no TPU-hardware run yet, and the
-in-kernel gathers (tag-row reads by set index) would need one-hot
-reformulation for a Mosaic lowering pass to be attempted.
+Status: interpret mode only. The TPU compiler (Mosaic, jax 0.9.0)
+refuses this kernel for a v5e, at every wave width tried (B = 8 and
+512): the ``(1, B)`` lane block over the ``(lanes, B)`` arrays breaks
+the rule that a block's last two dims be divisible by (8, 128) or equal
+the array's. Past that, the in-kernel gathers (tag-row reads by set
+index) would need a one-hot rewrite before Mosaic could lower them. So
+the kernel is an explicit ``backend="pallas"`` opt-in, and ``"auto"``
+resolves to ``"fused"``.
 """
 from __future__ import annotations
 

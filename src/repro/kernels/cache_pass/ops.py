@@ -52,13 +52,16 @@ record tuple the timing pass consumes. Backends:
     Wide waves also sort the wave by PC entry once (slots sharing a PC
     entry form segments) so each lane's counter reads are exact segment
     sums off one cumsum and the [pc_entries] tables take a single
-    conflict-free scatter-add at wave end. This is the CPU default.
+    conflict-free scatter-add at wave end. ``"auto"`` picks this
+    backend on every platform.
   * ``"pallas"`` — lane-chunked TPU kernel (kernel.py): grid over the
     L lanes with the cache state carried in VMEM scratch and all
     gather/scatter replaced by dense one-hot selects/reductions.
-    Validated under ``interpret=True`` off-TPU (no TPU-hardware run yet
-    — the caveat ROADMAP carries for wavefront_scan applies here too).
-  * ``"auto"``   — ``"pallas"`` on TPU, ``"fused"`` elsewhere.
+    Explicit opt-in only: on a TPU it is lowered through Mosaic, which
+    refuses it today (see kernel.py), and it raises rather than falling
+    back; off a TPU it runs in interpret mode, which is how the CPU
+    tests validate it.
+  * ``"auto"``   — ``"fused"`` on every platform.
 
 The differential suites pin fused == ref == pallas bitwise on every
 metric across the workload × policy matrix and on adversarial same-set
@@ -92,9 +95,7 @@ def resolve_backend(backend: str) -> str:
     if backend not in BACKENDS:
         raise ValueError(
             f"unknown cache backend {backend!r}; choose from {BACKENDS}")
-    if backend == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "fused"
-    return backend
+    return "fused" if backend == "auto" else backend
 
 
 def _fused_narrow(st: SimState, clf_b0: CLF.ClassifierState, tokens_b,
@@ -363,14 +364,13 @@ def _fused_sweep(st: SimState, clf_b0: CLF.ClassifierState, tokens_b,
 
 def wave_cache_pass(st: SimState, clf_b0: CLF.ClassifierState, tokens_b,
                     t0, addr_lb, pc_b, owt_b, slot_ok, prm: SimParams,
-                    pa: PolicyArrays, *, backend: str = "auto",
-                    interpret: bool = False) -> tuple:
+                    pa: PolicyArrays, *, backend: str = "auto") -> tuple:
     """One wave's cache pass under the selected backend.
 
     Deliberately NOT jitted here: the engine inlines it into its own
     jitted wave step (jitting at this level would force the [sets, ways]
-    state through a call boundary every wave). ``interpret`` forces the
-    Pallas kernel's interpreter mode; off-TPU it is implied.
+    state through a call boundary every wave). The pallas backend is
+    Mosaic-lowered on a TPU and interpreted everywhere else.
     """
     b = resolve_backend(backend)
     if b == "ref":
@@ -379,7 +379,6 @@ def wave_cache_pass(st: SimState, clf_b0: CLF.ClassifierState, tokens_b,
     if b == "pallas":
         return wave_cache_kernel(st, clf_b0, tokens_b, t0, addr_lb, pc_b,
                                  owt_b, slot_ok, prm, pa,
-                                 interpret=interpret
-                                 or jax.default_backend() != "tpu")
+                                 interpret=jax.default_backend() != "tpu")
     return _fused_sweep(st, clf_b0, tokens_b, t0, addr_lb, pc_b, owt_b,
                         slot_ok, prm, pa)

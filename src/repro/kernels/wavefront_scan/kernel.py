@@ -20,6 +20,14 @@ then advance the carry scratch. Occupancies are small integers, so the
 re-associated prefix sums are exact (< 2**24) and the kernel matches
 ref.py bit-for-bit on dyadic inputs; tests/test_kernels.py pins that
 under ``interpret=True`` on fuzzed queue loads.
+
+Status: interpret mode only. The TPU compiler (Mosaic, jax 0.9.0)
+refuses this kernel for a v5e. With one chunk (N <= 256 slots) it stops
+at ``jnp.cumsum``: "Unimplemented primitive in Pallas TPU lowering ...:
+cumsum". With more slots the ``(1, C)`` chunk block over the ``(k, C)``
+slot arrays breaks the rule that a block's last two dims be divisible
+by (8, 128) or equal the array's. So the kernel is an explicit
+``backend="pallas"`` opt-in, and ``"auto"`` resolves to ``"fused"``.
 """
 from __future__ import annotations
 

@@ -25,10 +25,12 @@ Backends:
     sweep with a combined (prefix-occ, running-max, predecessor) carry
     recovers bank, HP and LP service times together. Exact on dyadic
     inputs (integer occupancies; the chunked prefix sums re-associate,
-    which is exact below 2**24); validated under ``interpret=True`` on
-    CPU, where it is also automatically selected when forced.
-  * ``"auto"``   — ``"pallas"`` on TPU, ``"fused"`` elsewhere (the
-    pure-lax fallback keeps the SSE2-only CI box on the fast path).
+    which is exact below 2**24). Explicit opt-in only: on a TPU it is
+    lowered through Mosaic, which refuses it today (see kernel.py), and
+    it raises rather than falling back; off a TPU it runs in interpret
+    mode, which is how the CPU tests validate it.
+  * ``"auto"``   — ``"fused"`` on every platform: the one non-reference
+    backend that compiles for the chip and is pinned bitwise to ref.
 
 The differential suites pin fused == ref bitwise and pallas == ref on
 fuzzed queue loads (tests/test_kernels.py, test_engine_differential.py).
@@ -53,9 +55,7 @@ def resolve_backend(backend: str) -> str:
     if backend not in BACKENDS:
         raise ValueError(
             f"unknown scan backend {backend!r}; choose from {BACKENDS}")
-    if backend == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "fused"
-    return backend
+    return "fused" if backend == "auto" else backend
 
 
 def _scan_max(x):
@@ -207,14 +207,13 @@ def wave_queue_recovery(t_s, bank, use_l2, ch, row, go_dram, byp, hp,
                         carry: QueueCarry, *, banks: int, channels: int,
                         l2_svc: float, l2_lat: float, occ_rowhit: float,
                         occ_rowmiss: float, exact: bool,
-                        backend: str = "auto", interpret: bool = False):
+                        backend: str = "auto"):
     """One wave's queue recovery under the selected backend.
 
     Slot arrays are [N] in warp-major chronological order. Returns
     ``(t_head, t0, row_hit, new_carry)`` — see ref.py for the contract.
-    ``interpret`` only affects the pallas backend (and is forced on
-    automatically when pallas is requested off-TPU, so the kernel path
-    stays runnable on the CPU CI box).
+    The pallas backend is Mosaic-lowered on a TPU and interpreted
+    everywhere else.
 
     Deliberately NOT jitted here: the wavefront engine inlines it into
     its own jitted wave step (a nested pjit boundary would block XLA
@@ -229,10 +228,9 @@ def wave_queue_recovery(t_s, bank, use_l2, ch, row, go_dram, byp, hp,
         return _ref.wave_queue_recovery_ref(
             t_s, bank, use_l2, ch, row, go_dram, byp, hp, carry, **kw)
     if b == "pallas":
-        interp = interpret or jax.default_backend() != "tpu"
         t_head, t0, row_hit = wave_queue_kernel(
             t_s, bank, use_l2, ch, row, go_dram, byp, hp, carry,
-            interpret=interp, **kw)
+            interpret=jax.default_backend() != "tpu", **kw)
     else:
         t_head, t0, row_hit = _fused_core(
             t_s, bank, use_l2, ch, row, go_dram, byp, hp, carry, **kw)

@@ -47,15 +47,6 @@ HW = {  # TPU v5e-class single chip
 }
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` returns one dict on newer jax but a
-    per-device LIST of dicts on jax<=0.4.x — normalize to the dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
 def _opt_cfg(cfg) -> OptimizerConfig:
     # bf16 moments for >20B-param models: the optimizer-state lever that
     # fits grok-1-314b / qwen1.5-110b training on a 256-chip pod
@@ -148,7 +139,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool) -> dict:
         compiled = lowered.compile()
         t2 = time.time()
     ma = compiled.memory_analysis()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     summ = hlo_analysis.analyze(compiled.as_text())
     n_dev = mesh.devices.size
 

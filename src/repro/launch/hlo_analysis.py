@@ -82,15 +82,9 @@ def _split_operands(s: str) -> List[str]:
 
 
 def _operand_type(operand: str, types: Dict[str, str]) -> str:
-    """Type string of one operand. Newer XLA prints bare names
-    (``%get-tuple-element.4``); older XLA (jax<=0.4.x) prints the type
-    inline (``f32[4,32]{1,0} %get-tuple-element.4``) — prefer the inline
-    type, fall back to the name lookup."""
-    operand = operand.strip()
-    parts = operand.rsplit(None, 1)
-    if len(parts) == 2 and _SHAPE_RE.search(parts[0]):
-        return parts[0]
-    return types.get(operand.lstrip("%"), "")
+    """Type string of one operand; XLA prints operands as bare names
+    (``%get-tuple-element.4``), so it is looked up by name."""
+    return types.get(operand.strip().lstrip("%"), "")
 
 
 @dataclasses.dataclass

@@ -139,11 +139,6 @@ def test_compressed_psum_matches_psum():
     from repro.optim.optimizer import compressed_psum
     from repro.sharding import single_device_mesh
     import jax
-    # jax.shard_map is top-level only from 0.6; on the pinned 0.4.x
-    # runtime it lives in jax.experimental.shard_map
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
     mesh = single_device_mesh()
     x = jnp.asarray(np.random.default_rng(0).standard_normal((64,)),
                     jnp.float32)
@@ -151,7 +146,7 @@ def test_compressed_psum_matches_psum():
     def f(v):
         return compressed_psum(v, "data")
 
-    y = jax.jit(shard_map(
+    y = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=jax.sharding.PartitionSpec(),
         out_specs=jax.sharding.PartitionSpec()))(x)
     np.testing.assert_allclose(np.asarray(y), np.asarray(x), atol=0.02)

@@ -39,12 +39,11 @@ def test_analyzer_counts_scan_trips_and_dots():
 
 def test_analyzer_vs_cost_analysis_consistency():
     """Without loops, rolled dot flops ~= XLA's own flops count."""
-    from repro.launch.dryrun import cost_analysis_dict
     a = jnp.ones((64, 128))
     b = jnp.ones((128, 96))
     compiled = jax.jit(lambda a, b: a @ b).lower(a, b).compile()
     s = analyze(compiled.as_text())
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     np.testing.assert_allclose(s.dot_flops, ca["flops"], rtol=0.05)
 
 

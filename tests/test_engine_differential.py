@@ -500,7 +500,7 @@ def test_cache_fused_bitwise_wave_of_one():
 
 
 def test_cache_fused_bitwise_both_backends_fused():
-    """Both passes fused at once (the shipping CPU default) must still
+    """Both passes fused at once (the shipping default) must still
     equal the double-ref engine bitwise — the two fusions compose."""
     spec = WL.WORKLOADS["BFS"]
     tr = WL.generate(spec, seed=0)

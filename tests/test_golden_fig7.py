@@ -9,10 +9,9 @@ instead. If a change is INTENTIONAL (new RNG scheme, new layout), rerun
 
 and update GOLDEN_DERIVED in the same commit, saying so.
 
-Values generated by the tracegen counter-RNG path (ISSUE 2) on the
-pinned runtime (jax 0.4.37, CPU). Everything the harness prints is
-rounded to 4 decimals, so 1e-6 is an exact-match tolerance with
-headroom for float printing only.
+Values generated by the tracegen counter-RNG path on the CPU backend.
+Everything the harness prints is rounded to 4 decimals, so 1e-6 is an
+exact-match tolerance with headroom for float printing only.
 """
 import numpy as np
 import pytest

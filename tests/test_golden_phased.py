@@ -21,8 +21,8 @@ the probe-ratchet fix). Measured post-fix: best degrade-direction
 closure 153% (the window-32 rung now beats even oracle labels on
 PHASED256), best recovery-direction closure 85%.
 
-Values generated by the phased labeling ladder on the pinned runtime
-(jax 0.4.37, CPU), seed 0, wavefront engine. Everything the harness
+Values generated by the phased labeling ladder on the wavefront engine,
+seed 0, on the CPU backend. Everything the harness
 prints is rounded to 4–6 decimals, so 1e-6 is an exact-match tolerance
 with headroom for float printing only. Re-pinned in PR 7 (probe-ratchet
 fix) — every ladder rung that bypasses moved; before/after deltas are

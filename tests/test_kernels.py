@@ -221,10 +221,10 @@ def _wave_case(rng, n, dyadic=True, empty=False, banks=8, channels=4,
             carry)
 
 
-def _recover(args, backend, exact=False, interpret=True):
+def _recover(args, backend, exact=False):
     from repro.kernels.wavefront_scan.ops import wave_queue_recovery
     return wave_queue_recovery(*args, exact=exact, backend=backend,
-                               interpret=interpret, **_WS_KW)
+                               **_WS_KW)
 
 
 def _assert_wave_equal(a, b, slots_exactly=True, go_dram=None):
@@ -274,7 +274,7 @@ def test_wavefront_scan_pallas_interpret(n):
     rng = np.random.default_rng(n)
     args = _wave_case(rng, n, dyadic=True)
     _assert_wave_equal(_recover(args, "ref"),
-                       _recover(args, "pallas", interpret=True),
+                       _recover(args, "pallas"),
                        go_dram=args[5])
 
 
@@ -284,7 +284,7 @@ def test_wavefront_scan_pallas_nondyadic_close():
     rng = np.random.default_rng(11)
     args = _wave_case(rng, 600, dyadic=False)
     tr, t0r, rhr, cr = _recover(args, "ref")
-    tp, t0p, rhp, cp = _recover(args, "pallas", interpret=True)
+    tp, t0p, rhp, cp = _recover(args, "pallas")
     gd = np.asarray(args[5])
     np.testing.assert_allclose(np.asarray(tr), np.asarray(tp), atol=1e-3)
     np.testing.assert_allclose(np.asarray(t0r)[gd], np.asarray(t0p)[gd],
@@ -396,10 +396,9 @@ def _cache_case(rng, n_warps, b, lanes, prm, pa, addr_hi=60, empty=False):
     return st, (clf_b0, tokens_b, t0, addr_lb, pc_b, owt_b, slot_ok)
 
 
-def _cache_run(st, args, prm, pa, backend, interpret=False):
+def _cache_run(st, args, prm, pa, backend):
     from repro.kernels.cache_pass.ops import wave_cache_pass
-    return wave_cache_pass(st, *args, prm, pa, backend=backend,
-                           interpret=interpret)
+    return wave_cache_pass(st, *args, prm, pa, backend=backend)
 
 
 def _cache_assert_equal(a, b):
@@ -470,8 +469,7 @@ def test_cache_pass_pallas_interpret_tiny():
     rng = np.random.default_rng(9)
     st, args = _cache_case(rng, 12, 3, 4, prm, pa, addr_hi=40)
     ref = _cache_run(st, args, prm, pa, "ref")
-    _cache_assert_equal(ref, _cache_run(st, args, prm, pa, "pallas",
-                                        interpret=True))
+    _cache_assert_equal(ref, _cache_run(st, args, prm, pa, "pallas"))
 
 
 if HAVE_HYPOTHESIS:

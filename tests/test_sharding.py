@@ -13,8 +13,7 @@ from repro.sharding import (Logical, build_rules, spec_for, shard_act,
 
 
 def _mesh_16x16_abstract():
-    # jax 0.4.37's AbstractMesh takes ((name, size), ...) pairs
-    return jax.sharding.AbstractMesh((("data", 16), ("model", 16)))
+    return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_spec_basic():
@@ -46,8 +45,7 @@ def test_spec_missing_mesh_axis_removed():
 
 
 def test_multipod_batch_axes():
-    mesh = jax.sharding.AbstractMesh(
-        (("pod", 2), ("data", 16), ("model", 16)))
+    mesh = jax.sharding.AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     rules = build_rules(mesh)
     s = spec_for(("batch", None), (256, 4096), mesh, rules)
     assert s == P(("pod", "data"), None)

@@ -1,17 +1,7 @@
 """Emit the EXPERIMENTS.md §Dry-run / §Roofline tables from the per-cell
-JSONs produced by ``repro.launch.dryrun`` — plus, with ``--wavefront``,
-op-level timings of the wavefront engine's per-wave work (ISSUE 6).
+JSONs produced by ``repro.launch.dryrun``.
 
     PYTHONPATH=src python -m benchmarks.roofline [--dir results/dryrun]
-    PYTHONPATH=src python -m benchmarks.roofline --wavefront [--quick]
-
-The wavefront mode times the three per-wave components in isolation —
-wave selection (argsort vs top_k), the cache pass (ref lane scan vs the
-fused one-sweep backend, plus a sub-attribution of the ref scan into
-tag gather / RRIP+fill / EAF+PC updates / observe scatter), and the
-timing pass (unfused ref vs fused scan recovery) — at W ∈ {48, 256,
-1024, 4096}, which is how the fusion targets were ranked. JSON output
-rides ``benchmarks/run.py --json --only roofline_wavefront``.
 """
 from __future__ import annotations
 
@@ -19,10 +9,6 @@ import argparse
 import glob
 import json
 import os
-import time
-from typing import Dict, List, Tuple
-
-import numpy as np
 
 
 def load(dirpath):
@@ -90,246 +76,10 @@ def summarize(cells):
           f"(documented), {n_err} errors")
 
 
-# ---------------------------------------------------------------------------
-# --wavefront: op-level timing of the engine's per-wave components
-# ---------------------------------------------------------------------------
-
-_WF_WARPS = (48, 256, 1024, 4096)
-_WF_WARPS_QUICK = (48, 256)
-
-
-def _timed_us(fn, *args, reps: int = 5) -> float:
-    """Warm mean wall-clock of a jitted fn, in microseconds."""
-    import jax
-    out = fn(*args)
-    jax.tree.map(lambda x: x.block_until_ready(), out)
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        out = fn(*args)
-    jax.tree.map(lambda x: x.block_until_ready(), out)
-    return (time.perf_counter() - t0) / reps * 1e6
-
-
-def _wave_inputs(n_warps: int, lanes: int, prm, rng):
-    """One synthetic wave at engine-realistic occupancy: B earliest-ready
-    warps (sorted ready times), dense lane vectors, mixed hit/bypass/
-    priority mix. Deterministic per W."""
-    import jax.numpy as jnp
-    from repro.core.engine import wavefront as WF
-    B = WF.default_wave_size(n_warps)
-    n = B * lanes
-    ready = jnp.asarray(np.sort(rng.uniform(0, 50, n_warps)), jnp.float32)
-    t_s = jnp.repeat(jnp.sort(ready)[:B], lanes) \
-        + jnp.tile(jnp.arange(lanes, dtype=jnp.float32), B) * prm.lane_skew
-    lines = jnp.asarray(rng.integers(0, 1 << 20, (B, lanes)), jnp.int32)
-    valid = jnp.asarray(rng.random(n) < 0.95)
-    byp = jnp.asarray(rng.random(n) < 0.15) & valid
-    hit = jnp.asarray(rng.random(n) < 0.4) & valid & ~byp
-    hp = jnp.asarray(rng.random(n) < 0.5)
-    return B, ready, t_s, lines, valid, byp, hit, hp
-
-
-def wavefront_ops(quick: bool = False) -> Tuple[List[dict], Dict]:
-    """Per-wave op-level timings: selection vs cache pass vs timing pass
-    at each W. Every op is timed warm and in isolation under its own
-    ``jax.jit`` (the engine inlines them into one jitted wave step, so
-    these are attribution numbers, not additive wall-clock)."""
-    import jax
-    import jax.numpy as jnp
-    from repro.core import baselines as BL
-    from repro.core.engine import request as REQ
-    from repro.core.engine import wavefront as WF
-    from repro.core.engine.state import SimParams, init_state
-    from repro.kernels.cache_pass import ops as CPASS
-    from repro.kernels.cache_pass import ref as CREF
-    from repro.kernels.wavefront_scan import ops as WSCAN
-    from repro.kernels.wavefront_scan.ref import QueueCarry
-    from repro.policy import ops as POL
-    from repro.policy import to_arrays
-
-    prm = SimParams()
-    lanes = 16
-    pa = to_arrays(BL.MEDIC)
-    rows: List[dict] = []
-    derived: Dict[str, object] = {}
-
-    for n_warps in (_WF_WARPS_QUICK if quick else _WF_WARPS):
-        rng = np.random.default_rng(n_warps)
-        B, ready, t_s, lines, valid, byp, hit, hp = _wave_inputs(
-            n_warps, lanes, prm, rng)
-        tokens = POL.pcal_tokens(pa, n_warps)
-
-        # ---- wave selection: full argsort vs top-B ------------------------
-        sel_sort = jax.jit(lambda r: jnp.argsort(r)[:B])
-        sel_topk = jax.jit(lambda r: jax.lax.top_k(-r, B)[1])
-        t_sort = _timed_us(sel_sort, ready)
-        t_topk = _timed_us(sel_topk, ready)
-
-        # ---- cache pass: ref lane scan vs fused one-sweep -----------------
-        st0 = init_state(n_warps, prm)
-        w_sel = jnp.asarray(
-            rng.choice(n_warps, size=B, replace=False), jnp.int32)
-        pc_b = jnp.asarray(rng.integers(0, 64, B), jnp.int32)
-        owt_b = jnp.zeros((B,), jnp.int32)
-        slot_ok = jnp.ones((B,), bool)
-        t0w = jnp.sort(ready)[:B]
-        lines_lb = jnp.swapaxes(lines, 0, 1)
-
-        def cache_fn(backend):
-            def run(st, t0v, addr_lb):
-                clf_b0 = jax.tree.map(lambda a: a[w_sel], st.clf)
-                st, clf_b, recs = CPASS.wave_cache_pass(
-                    st, clf_b0, tokens[w_sel], t0v, addr_lb, pc_b,
-                    owt_b, slot_ok, prm, pa, backend=backend)
-                st = st._replace(clf=jax.tree.map(
-                    lambda full, b: full.at[w_sel].set(b), st.clf, clf_b))
-                return st, recs
-            return jax.jit(run)
-        cargs = (st0, t0w, lines_lb)
-        t_cache_ref = _timed_us(cache_fn("ref"), *cargs)
-        t_cache_fused = _timed_us(cache_fn("fused"), *cargs)
-
-        # ---- cache-pass sub-attribution: the ref scan's four stages -------
-        # Each stage timed as its own L-lane scan carrying only that
-        # mechanism's state — attribution of where the ref number goes,
-        # not additive wall-clock.
-        lane_ids = jnp.arange(lanes, dtype=jnp.int32)
-        sidx_lb = REQ.set_index(lines_lb, prm)
-        valid_lb = lines_lb >= 0
-
-        @jax.jit
-        def sub_tag_gather(tags, addr_lb):
-            def step(t, xs):
-                sidx, addr = xs
-                tset = t[sidx]
-                is_line = tset == addr[:, None]
-                hit_way = jnp.argmax(is_line, axis=1)
-                row = jnp.where(
-                    jnp.arange(prm.ways)[None, :] == hit_way[:, None],
-                    addr[:, None], tset)
-                t = t.at[sidx].set(row, mode="drop")
-                return t, jnp.any(is_line, axis=1)
-            return jax.lax.scan(step, tags, (sidx_lb, addr_lb))
-
-        @jax.jit
-        def sub_rrip_fill(rrip, meta):
-            def step(c, sidx):
-                r, m = c
-                rset = r[sidx]
-                shift = prm.rrip_max - jnp.max(rset, axis=1)
-                rset = rset + shift[:, None]
-                victim = jnp.argmax(rset, axis=1)
-                voh = jnp.arange(prm.ways)[None, :] == victim[:, None]
-                r = r.at[sidx].set(jnp.where(voh, 0, rset), mode="drop")
-                m = m.at[sidx].set(jnp.where(voh, 1, m[sidx]), mode="drop")
-                return (r, m), victim
-            return jax.lax.scan(step, (rrip, meta), sidx_lb)
-
-        @jax.jit
-        def sub_eaf_pc(eaf, pch, pca, pcr):
-            def step(c, xs):
-                e, h, a, q = c
-                addr, v = xs
-                eidx = REQ.eaf_index(addr, prm)
-                e = e.at[jnp.where(v, eidx, prm.eaf_bits)].set(
-                    1, mode="drop")
-                pidx2 = REQ.pc_index(pc_b, prm)
-                h = h.at[pidx2].add(v.astype(jnp.int32))
-                a = a.at[pidx2].add(v.astype(jnp.int32))
-                q = q.at[pidx2].add(v.astype(jnp.int32))
-                return (e, h, a, q), None
-            return jax.lax.scan(step, (eaf, pch, pca, pcr),
-                                (lines_lb, valid_lb))
-
-        @jax.jit
-        def sub_observe(clf, addr_lb):
-            clf_b = jax.tree.map(lambda a: a[w_sel], clf)
-
-            def step(cb, addr):
-                v = (addr >= 0).astype(jnp.int32)
-                return CREF.observe_vec(cb, addr >= 0, v, v, prm, pa), None
-            clf_b, _ = jax.lax.scan(step, clf_b, addr_lb)
-            return jax.tree.map(lambda full, b: full.at[w_sel].set(b),
-                                clf, clf_b)
-        t_sub_tag = _timed_us(sub_tag_gather, st0.tags, lines_lb)
-        t_sub_rrip = _timed_us(sub_rrip_fill, st0.rrip, st0.meta_type)
-        t_sub_eaf = _timed_us(sub_eaf_pc, st0.eaf, st0.pc_hits,
-                              st0.pc_acc, st0.pc_req)
-        t_sub_obs = _timed_us(sub_observe, st0.clf, lines_lb)
-
-        # ---- timing pass: unfused ref vs fused scan recovery --------------
-        addr_s = jnp.repeat(lines, 1, axis=0).reshape(-1)
-        bank = REQ.bank_index(addr_s, prm)
-        ch = REQ.dram_channel(addr_s, prm)
-        row = REQ.dram_row(addr_s, prm)
-        use_l2 = valid & ~byp
-        go_dram = valid & (byp | ~hit)
-        carry = QueueCarry(
-            bank_free=jnp.zeros((prm.banks,), jnp.float32),
-            bank_ts=jnp.full((prm.banks,), -jnp.inf),
-            hp_free=jnp.zeros((prm.dram_channels,), jnp.float32),
-            hp_ts=jnp.full((prm.dram_channels,), -jnp.inf),
-            hp_sa=jnp.full((prm.dram_channels,), -jnp.inf),
-            lp_free=jnp.zeros((prm.dram_channels,), jnp.float32),
-            lp_ts=jnp.full((prm.dram_channels,), -jnp.inf),
-            lp_sa=jnp.full((prm.dram_channels,), -jnp.inf),
-            cur_row=jnp.full((prm.dram_channels,), -1, jnp.int32))
-
-        def timing_fn(backend):
-            kw = dict(banks=prm.banks, channels=prm.dram_channels,
-                      l2_svc=prm.l2_svc, l2_lat=prm.l2_lat,
-                      occ_rowhit=prm.occ_rowhit,
-                      occ_rowmiss=prm.occ_rowmiss, exact=False,
-                      backend=backend)
-            return jax.jit(lambda *a: WSCAN.wave_queue_recovery(*a, **kw))
-        targs = (t_s, bank, use_l2, ch, row, go_dram, byp, hp, carry)
-        t_ref = _timed_us(timing_fn("ref"), *targs)
-        t_fused = _timed_us(timing_fn("fused"), *targs)
-
-        for op, us in (("select_argsort", t_sort), ("select_topk", t_topk),
-                       ("cache_ref", t_cache_ref),
-                       ("cache_fused", t_cache_fused),
-                       ("cache_sub_tag_gather", t_sub_tag),
-                       ("cache_sub_rrip_fill", t_sub_rrip),
-                       ("cache_sub_eaf_pc", t_sub_eaf),
-                       ("cache_sub_observe", t_sub_obs),
-                       ("timing_ref", t_ref),
-                       ("timing_fused", t_fused)):
-            rows.append({"W": n_warps, "B": int(B), "op": op,
-                         "wall_us": round(us, 1)})
-        derived[f"timing_speedup_{n_warps}"] = round(t_ref / t_fused, 2)
-        derived[f"select_speedup_{n_warps}"] = round(t_sort / t_topk, 2)
-        derived[f"cache_speedup_{n_warps}"] = round(
-            t_cache_ref / t_cache_fused, 2)
-        biggest = max((("cache_ref", t_cache_ref), ("timing_ref", t_ref),
-                       ("select_argsort", t_sort)), key=lambda kv: kv[1])
-        derived[f"unfused_dominant_{n_warps}"] = biggest[0]
-    return rows, derived
-
-
-def emit_wavefront(rows, derived):
-    print("\n### Wavefront per-wave op timings (warm, isolated jits)\n")
-    print("| W | B | op | wall us |")
-    print("|---|---|---|---|")
-    for r in rows:
-        print(f"| {r['W']} | {r['B']} | {r['op']} | {r['wall_us']} |")
-    print()
-    for k in sorted(derived):
-        print(f"- {k}: {derived[k]}")
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--dir", default="results/dryrun")
-    ap.add_argument("--wavefront", action="store_true",
-                    help="time the wavefront engine's per-wave ops "
-                         "instead of formatting dryrun tables")
-    ap.add_argument("--quick", action="store_true",
-                    help="--wavefront at W in {48, 256} only")
     args = ap.parse_args()
-    if args.wavefront:
-        emit_wavefront(*wavefront_ops(quick=args.quick))
-        return
     cells = load(args.dir)
     emit(cells, "16x16")
     emit(cells, "2x16x16")

@@ -60,8 +60,8 @@ def main() -> None:
             os.remove(args.json)
 
     from benchmarks import (api_bench, engine_bench, kernel_micro,
-                            paper_figures, phased_bench, roofline,
-                            serving_ab, sharded_bench, tracegen_bench)
+                            paper_figures, phased_bench, serving_ab,
+                            sharded_bench, tracegen_bench)
     from repro.core import workloads as WL
 
     wls = ("BFS", "SSSP", "BP", "CONS") if args.quick else WL.WORKLOAD_NAMES
@@ -78,10 +78,6 @@ def main() -> None:
         # in-run unfused-vs-fused wavefront A/B (ISSUE 6 acceptance:
         # fused_speedup_wide1k >= 1.5 at 1024 warps, same process)
         "engine_fused": lambda: engine_bench.fused_ab(quick=args.quick),
-        # op-level attribution of the per-wave cost (selection vs cache
-        # pass vs timing pass) behind roofline.py --wavefront
-        "roofline_wavefront": lambda: roofline.wavefront_ops(
-            quick=args.quick),
         # api-layer overhead is always measured on the quick suite (the
         # gated configuration); the full fig7 suite is the same single
         # shape bucket with more scenarios

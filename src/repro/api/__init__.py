@@ -19,10 +19,10 @@ byte-identical re-expression of them.
 """
 from repro.api import registry
 from repro.api.experiment import Experiment, Plan, PlanCall, run
-from repro.api.results import ResultBlock, ResultSet
+from repro.api.results import ResultBlock, ResultSet, WaveCounts
 from repro.api.scenario import Scenario
 
 __all__ = [
     "Experiment", "Plan", "PlanCall", "ResultBlock", "ResultSet",
-    "Scenario", "registry", "run",
+    "Scenario", "WaveCounts", "registry", "run",
 ]

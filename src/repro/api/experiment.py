@@ -24,20 +24,34 @@ keeps the golden fig7 suite byte-identical through the migration.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro import sharding as SH
-from repro.api.results import ResultBlock, ResultSet
+from repro.api.results import ResultBlock, ResultSet, WaveCounts
 from repro.api.scenario import Scenario, Shape
 from repro.core.engine import (SimParams, simulate_sweep,
                                validate_engine_args, validate_mesh_args)
+from repro.core.engine.wavefront import resolve_wave_size
 from repro.policy import Policy
 
 _TRACE_KEYS = ("lines", "pcs", "compute_gap", "archetype", "oracle_wtype")
+
+
+@contextlib.contextmanager
+def span(spans: Dict[str, float], name: str):
+    """A program span: its host-clock seconds go into ``spans[name]``,
+    and a profiler annotation of the same name puts it on the clock of
+    a device trace, where one is being taken."""
+    with jax.profiler.TraceAnnotation(name):
+        t0 = time.perf_counter()
+        yield
+        spans[name] = time.perf_counter() - t0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,23 +97,24 @@ class PlanCall:
         and shared across policies, so an A/B always compares on the
         IDENTICAL arrival sequence."""
         from repro.serving.sim import generate_serving, simulate_serving
-        t0 = time.perf_counter()
-        entries: List[Tuple[str, int]] = []
-        cols: List[List[Dict[str, float]]] = []   # [F][P] metric dicts
-        for s in self.scenarios:
-            for seed in s.seeds:
-                reqs = generate_serving(s.spec, seed)
-                entries.append((s.name, seed))
-                cols.append([simulate_serving(
-                    reqs, s.spec, policy=pol,
-                    pool_backend=exp.pool_backend)["metrics"]
-                    for pol in exp.policies])
-        metrics = {k: np.asarray(
-            [[cols[f][p][k] for f in range(len(entries))]
-             for p in range(len(exp.policies))], np.float64)
-            for k in cols[0][0]}
-        return ResultBlock(tuple(entries), metrics,
-                           time.perf_counter() - t0)
+        spans: Dict[str, float] = {}
+        with span(spans, "repro.call"):
+            entries: List[Tuple[str, int]] = []
+            cols: List[List[Dict[str, float]]] = []   # [F][P] metric dicts
+            for s in self.scenarios:
+                for seed in s.seeds:
+                    reqs = generate_serving(s.spec, seed)
+                    entries.append((s.name, seed))
+                    cols.append([simulate_serving(
+                        reqs, s.spec, policy=pol,
+                        pool_backend=exp.pool_backend)["metrics"]
+                        for pol in exp.policies])
+            metrics = {k: np.asarray(
+                [[cols[f][p][k] for f in range(len(entries))]
+                 for p in range(len(exp.policies))], np.float64)
+                for k in cols[0][0]}
+        return ResultBlock(tuple(entries), metrics, spans["repro.call"],
+                           spans=spans)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,7 +158,12 @@ class Plan:
         return "\n".join(lines)
 
     def execute(self, keep_traces: bool = False) -> ResultSet:
-        """Materialize traces and run every planned call."""
+        """Materialize traces and run every planned call.
+
+        Each call records two program spans in its block:
+        ``repro.tracegen`` (materializing its traces) and ``repro.call``
+        (the engine call and the copy of its results back, the interval
+        ``wall_s`` times); a serving call records ``repro.call`` only."""
         exp = self.experiment
         blocks: List[ResultBlock] = []
         for call in self.calls:
@@ -151,7 +171,9 @@ class Plan:
                 blocks.append(call.execute_serving(exp))
                 continue
             n_instr, n_warps, lanes = call.shape
-            parts = [s.materialize() for s in call.scenarios]
+            spans: Dict[str, float] = {}
+            with span(spans, "repro.tracegen"):
+                parts = [s.materialize() for s in call.scenarios]
             # a bucket may mix constant-intensity scenarios (scalar gap
             # per seed, [S]) with phased ones ([S, I]): broadcast the
             # scalars so the stacked axis is uniform
@@ -163,19 +185,27 @@ class Plan:
                             g[:, None], (g.shape[0], n_instr))
             tr = {k: np.concatenate([p[k] for p in parts])
                   for k in _TRACE_KEYS}
-            t0 = time.perf_counter()
-            out = simulate_sweep(
-                np.asarray(tr["lines"]), np.asarray(tr["pcs"]),
-                np.asarray(tr["compute_gap"]), exp.policies,
-                n_warps=n_warps, lanes=lanes, prm=exp.prm,
-                engine=call.engine, wave_size=call.wave_size,
-                scan_backend=call.scan_backend,
-                cache_backend=call.cache_backend,
-                oracle_types=np.asarray(tr["oracle_wtype"]),
-                mesh=call.mesh, policy_axes=call.policy_axes,
-                seed_axes=call.seed_axes, warp_axes=call.warp_axes)
-            out = {k: np.asarray(v) for k, v in out.items()}  # [P, F, ...]
-            wall = time.perf_counter() - t0
+            counters: Dict[str, object] = {}
+            with span(spans, "repro.call"):
+                out = simulate_sweep(
+                    np.asarray(tr["lines"]), np.asarray(tr["pcs"]),
+                    np.asarray(tr["compute_gap"]), exp.policies,
+                    n_warps=n_warps, lanes=lanes, prm=exp.prm,
+                    engine=call.engine, wave_size=call.wave_size,
+                    scan_backend=call.scan_backend,
+                    cache_backend=call.cache_backend,
+                    oracle_types=np.asarray(tr["oracle_wtype"]),
+                    mesh=call.mesh, policy_axes=call.policy_axes,
+                    seed_axes=call.seed_axes, warp_axes=call.warp_axes,
+                    counters=counters)
+                out = {k: np.asarray(v)                   # [P, F, ...]
+                       for k, v in out.items()}
+            waves = None
+            if "waves" in counters:
+                waves = WaveCounts(
+                    np.asarray(counters["waves"]),
+                    resolve_wave_size(call.wave_size, n_warps),
+                    n_instr * n_warps)
             entries = tuple((s.name, seed) for s in call.scenarios
                             for seed in s.seeds)
             traces = None
@@ -183,7 +213,8 @@ class Plan:
                 traces = tuple(
                     {k: tr[k][f] for k in _TRACE_KEYS}
                     for f in range(call.flat))
-            blocks.append(ResultBlock(entries, out, wall, traces))
+            blocks.append(ResultBlock(entries, out, spans["repro.call"],
+                                      traces, waves, spans))
         meta = {"experiment": exp.name, "engine": exp.engine,
                 "n_calls": self.n_calls,
                 "n_executables": self.n_executables}

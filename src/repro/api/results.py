@@ -25,18 +25,35 @@ import numpy as np
 
 
 @dataclasses.dataclass(frozen=True)
+class WaveCounts:
+    """The wavefront engine's wave loop in ONE call: ``waves[p, f]`` is
+    the number of waves simulation (p, f) ran, ``wave_size`` the warps
+    a wave holds (B), and ``warp_instructions`` the I·W instructions
+    each simulation services. Under vmap the device runs
+    ``waves.max()`` waves of B slots for every simulation."""
+    waves: np.ndarray
+    wave_size: int
+    warp_instructions: int
+
+
+@dataclasses.dataclass(frozen=True)
 class ResultBlock:
     """Results of ONE emitted simulate_sweep call.
 
     ``entries[f]`` labels flat index ``f`` as (scenario name, seed);
     ``metrics`` maps metric name to an array ``[P, F, ...]``; ``wall_s``
     is the wall-clock of the whole call (compile included on the first
-    run); ``traces[f]`` optionally keeps the input trace arrays.
+    run); ``traces[f]`` optionally keeps the input trace arrays;
+    ``waves`` counts the wave loop's iterations (None off the wavefront
+    engine); ``spans`` maps each program span of the call
+    (``repro.tracegen``, ``repro.call``) to its host-clock seconds.
     """
     entries: Tuple[Tuple[str, int], ...]
     metrics: Dict[str, np.ndarray]
     wall_s: float
     traces: Optional[Tuple[Dict[str, np.ndarray], ...]] = None
+    waves: Optional[WaveCounts] = None
+    spans: Dict[str, float] = dataclasses.field(default_factory=dict)
 
 
 class ResultSet:
@@ -259,6 +276,15 @@ class ResultSet:
 
     def call_walls(self) -> Tuple[float, ...]:
         return tuple(b.wall_s for b in self._blocks)
+
+    def span_s(self, name: str) -> float:
+        """Host-clock seconds of the program span ``name``, summed over
+        every emitted call (0.0 where no call recorded it)."""
+        return float(sum(b.spans.get(name, 0.0) for b in self._blocks))
+
+    def wave_counts(self) -> Tuple[WaveCounts, ...]:
+        """The wave counts of every wavefront call."""
+        return tuple(b.waves for b in self._blocks if b.waves is not None)
 
     def wall_of(self, scenario: str, seed: Optional[int] = None) -> float:
         """Wall of the call that produced ``scenario`` (same-bucket

@@ -124,11 +124,19 @@ def validate_mesh_args(mesh, policy_axes=None, seed_axes=None,
             f"engine={engine!r} would silently ignore it")
 
 
+def _event_core(*args, **kwargs):
+    """The event engine in the wavefront engine's ``(metrics, waves)``
+    form: it runs no waves."""
+    return _event.simulate_core(*args, **kwargs), None
+
+
 def _core(engine: str, wave_size: Optional[int], scan_backend: str,
           cache_backend: str, warp_mesh=None, warp_axes=None):
+    """The engine's ``simulate_core``, returning ``(metrics, waves)``
+    (``waves`` is None on the event engine)."""
     validate_engine_args(engine, wave_size, scan_backend, cache_backend)
     if engine == "event":
-        return _event.simulate_core
+        return _event_core
     return partial(_wavefront.simulate_core, wave_size=wave_size,
                    scan_backend=scan_backend, cache_backend=cache_backend,
                    warp_mesh=warp_mesh, warp_axes=warp_axes)
@@ -165,7 +173,7 @@ def _simulate_one(trace_lines, trace_pcs, compute_gap, oracle_types, pa, *,
     core = _core(engine, wave_size, scan_backend, cache_backend,
                  warp_mesh, warp_axes)
     return core(trace_lines, trace_pcs, compute_gap, oracle_types, pa,
-                n_warps=n_warps, lanes=lanes, prm=prm)
+                n_warps=n_warps, lanes=lanes, prm=prm)[0]
 
 
 @partial(jax.jit,
@@ -179,6 +187,9 @@ def _simulate_batch(trace_lines, trace_pcs, compute_gap, oracle_types,
                     scan_backend: str = "auto",
                     cache_backend: str = "auto",
                     warp_mesh=None, warp_axes=None):
+    """The vmapped sweep: ``(metrics, waves)``, each with leading axes
+    ``[P]`` or ``[P, S]``; ``waves`` (i32, the wave loop's iterations
+    per simulation) is None on the event engine."""
     one = partial(_core(engine, wave_size, scan_backend, cache_backend,
                         warp_mesh, warp_axes),
                   n_warps=n_warps, lanes=lanes, prm=prm)
@@ -252,7 +263,8 @@ def simulate_sweep(trace_lines, trace_pcs, compute_gap,
                    scan_backend: str = "auto",
                    cache_backend: str = "auto",
                    oracle_types=None, mesh=None, policy_axes=None,
-                   seed_axes=None, warp_axes=None) -> Dict[str, Any]:
+                   seed_axes=None, warp_axes=None,
+                   counters: Optional[dict] = None) -> Dict[str, Any]:
     """Run a whole policy sweep in ONE jitted, vmapped call.
 
     trace_lines may be [I, W, L] (one workload instance — outputs get a
@@ -278,6 +290,12 @@ def simulate_sweep(trace_lines, trace_pcs, compute_gap,
 
     Metrics match per-policy `simulate` calls bit-for-bit on either
     engine (the parity is enforced by tests/test_policy_engine.py).
+
+    ``counters``, a dict given, receives the program's counters of the
+    call: on the wavefront engine ``"waves"``, the i32 number of waves
+    each simulation ran, with the metrics' leading ``[P]`` or ``[P, S]``
+    axes (under vmap the device runs as many waves as the longest
+    simulation of the call needs).
     """
     validate_engine_args(engine, wave_size, scan_backend, cache_backend)
     validate_mesh_args(mesh, policy_axes, seed_axes, warp_axes, engine)
@@ -298,13 +316,14 @@ def simulate_sweep(trace_lines, trace_pcs, compute_gap,
         gap_res = s_res if jnp.ndim(compute_gap) >= 1 else None
         compute_gap = SH.put_leading(compute_gap, mesh, gap_res)
         w_res = SH.resolve_axes(mesh, warp_axes, n_warps)
-    return _simulate_batch(trace_lines, trace_pcs, compute_gap, oracle,
-                           pa, n_warps=n_warps, lanes=lanes, prm=prm,
-                           engine=engine, wave_size=wave_size,
-                           scan_backend=scan_backend,
-                           cache_backend=cache_backend,
-                           warp_mesh=mesh if w_res is not None else None,
-                           warp_axes=w_res)
+    stats, waves = _simulate_batch(
+        trace_lines, trace_pcs, compute_gap, oracle, pa, n_warps=n_warps,
+        lanes=lanes, prm=prm, engine=engine, wave_size=wave_size,
+        scan_backend=scan_backend, cache_backend=cache_backend,
+        warp_mesh=mesh if w_res is not None else None, warp_axes=w_res)
+    if counters is not None and waves is not None:
+        counters["waves"] = waves
+    return stats
 
 
 __all__ = [

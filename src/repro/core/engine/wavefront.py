@@ -67,7 +67,7 @@ stress matrix (tracegen/stress.py) end-to-end.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, NamedTuple, Optional
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -249,14 +249,23 @@ def _timing_pass(st: SimState, an: QueueAnchors, recs, prm: SimParams,
     return new_st, new_an, t_done_lb
 
 
+def resolve_wave_size(wave_size: Optional[int], n_warps: int) -> int:
+    """The warps one wave holds: ``wave_size``, else the default, and
+    never more than the population."""
+    return max(1, min(wave_size or default_wave_size(n_warps), n_warps))
+
+
 def simulate_core(trace_lines, trace_pcs, compute_gap, oracle_types,
                   pa: PolicyArrays, *, n_warps: int, lanes: int,
                   prm: SimParams, wave_size: Optional[int] = None,
                   scan_backend: str = "auto",
                   cache_backend: str = "auto",
-                  warp_mesh=None, warp_axes=None) -> Dict[str, Any]:
+                  warp_mesh=None, warp_axes=None
+                  ) -> Tuple[Dict[str, Any], Any]:
     """One workload × one policy on the wavefront engine. Vmappable.
 
+    Returns ``(metrics, waves)``: the metrics dict of
+    ``event.simulate_core`` and the i32 count of waves the loop ran.
     ``compute_gap`` is a scalar or f32[I]; ``oracle_types`` i32[I, W]
     (same contract as ``event.simulate_core``). ``scan_backend`` selects
     the timing-pass implementation (``wavefront_scan.BACKENDS``) and
@@ -282,7 +291,7 @@ def simulate_core(trace_lines, trace_pcs, compute_gap, oracle_types,
     n_instr = trace_lines.shape[0]
     shard_w0 = _warp_constraint(warp_mesh, warp_axes, 0)
     shard_w1 = _warp_constraint(warp_mesh, warp_axes, 1)
-    B = max(1, min(wave_size or default_wave_size(n_warps), n_warps))
+    B = resolve_wave_size(wave_size, n_warps)
     # wave-count CAP (the while_loop usually exits earlier, see module
     # docstring): phase 1 (>= B warps active) services B instructions
     # per wave; once fewer than B warps remain every wave advances all
@@ -310,84 +319,98 @@ def simulate_core(trace_lines, trace_pcs, compute_gap, oracle_types,
 
     def wave_step(carry):
         st, an, ready, ptr, ratio_t, k = carry
-        active = ptr < n_instr
-        # wave = the B earliest-ready active warps, slots in
-        # chronological order, ties by warp id (the event loop's
-        # argmin). top_k on the negated keys returns exactly the first
-        # B entries of the stable ascending argsort (equal keys by
-        # lower index) at O(W log B) instead of the full O(W log W)
-        # sort — tie-parity is pinned by the differential suite.
-        if fused:
-            w_sel = jax.lax.top_k(
-                jnp.where(active, -ready, _NEG), B)[1].astype(I32)
-        else:
-            order = jnp.argsort(jnp.where(active, ready, jnp.inf))
-            w_sel = order[:B].astype(I32)
-        slot_ok = active[w_sel]
-        i_sel = ptr[w_sel]
-        t0 = ready[w_sel]
-        lines_b = lines_wi[w_sel, i_sel]             # [B, L]
-        pc_b = pcs_wi[w_sel, i_sel]                  # [B]
-        owt_b = oracle_wi[w_sel, i_sel]              # [B]
+        # each pass runs in a named scope of its own. The names reach
+        # the device trace as op metadata only (no op changes), and the
+        # benchmark finds each pass's device time by them
+        # (bench/lib/scopes.py): keep them through refactors
+        with jax.named_scope("wave_select"):
+            active = ptr < n_instr
+            # wave = the B earliest-ready active warps, slots in
+            # chronological order, ties by warp id (the event loop's
+            # argmin). top_k on the negated keys returns exactly the
+            # first B entries of the stable ascending argsort (equal
+            # keys by lower index) at O(W log B) instead of the full
+            # O(W log W) sort — tie-parity is pinned by the
+            # differential suite.
+            if fused:
+                w_sel = jax.lax.top_k(
+                    jnp.where(active, -ready, _NEG), B)[1].astype(I32)
+            else:
+                order = jnp.argsort(jnp.where(active, ready, jnp.inf))
+                w_sel = order[:B].astype(I32)
+            slot_ok = active[w_sel]
+            i_sel = ptr[w_sel]
+            t0 = ready[w_sel]
+            lines_b = lines_wi[w_sel, i_sel]             # [B, L]
+            pc_b = pcs_wi[w_sel, i_sel]                  # [B]
+            owt_b = oracle_wi[w_sel, i_sel]              # [B]
 
-        # wave-resident classifier rows: gather once, carry [B] slices
-        # through the pass, scatter back once (wave warp ids are
-        # distinct, so nothing else touches the rows mid-wave — see
-        # cache_pass.ref.observe_vec)
-        clf_b0 = jax.tree.map(lambda a: a[w_sel], st.clf)
-        tokens_b = tokens[w_sel]
-        st, clf_b, recs = CPASS.wave_cache_pass(
-            st, clf_b0, tokens_b, t0, jnp.swapaxes(lines_b, 0, 1), pc_b,
-            owt_b, slot_ok, prm, pa, backend=cache_backend)
-        st = st._replace(clf=jax.tree.map(
-            lambda full, b: full.at[w_sel].set(b), st.clf, clf_b))
-        st, an, t_done = _timing_pass(st, an, recs, prm, scan_backend)
+            # wave-resident classifier rows: gather once, carry [B]
+            # slices through the pass, scatter back once (wave warp ids
+            # are distinct, so nothing else touches the rows mid-wave —
+            # see cache_pass.ref.observe_vec)
+            clf_b0 = jax.tree.map(lambda a: a[w_sel], st.clf)
+            tokens_b = tokens[w_sel]
 
-        (_, _, valid_lb, byp_lb, use_lb, hit_lb, _, vt_lb, ev_lb) = recs
-        # hoisted write-only bookkeeping: one update per wave instead of
-        # one per lane (integer adds — exact either way)
-        m = st.metrics
-        metrics = dict(m)
-        metrics["l2_accesses"] = m["l2_accesses"] + jnp.sum(
-            use_lb.astype(I32))
-        metrics["l2_hits"] = m["l2_hits"] + jnp.sum(hit_lb.astype(I32))
-        metrics["bypasses"] = m["bypasses"] + jnp.sum(
-            byp_lb.astype(I32))
-        # one-hot over the type bins (victim_type is always a written
-        # label, in range) instead of an [N] scatter-add, which XLA:CPU
-        # serializes per element
-        n_types = m["evictions_by_type"].shape[0]
-        vt_oh = vt_lb.reshape(-1)[:, None] \
-            == jnp.arange(n_types, dtype=I32)[None, :]
-        metrics["evictions_by_type"] = m["evictions_by_type"] + jnp.sum(
-            jnp.where(vt_oh, ev_lb.reshape(-1)[:, None].astype(I32), 0),
-            axis=0)
-        st = st._replace(
-            tot_hits=st.tot_hits.at[w_sel].add(
-                jnp.sum(hit_lb.astype(I32), axis=0)),
-            tot_acc=st.tot_acc.at[w_sel].add(
-                jnp.sum(valid_lb.astype(I32), axis=0)),
-            metrics=metrics)
+        with jax.named_scope("cache_pass"):
+            st, clf_b, recs = CPASS.wave_cache_pass(
+                st, clf_b0, tokens_b, t0, jnp.swapaxes(lines_b, 0, 1),
+                pc_b, owt_b, slot_ok, prm, pa, backend=cache_backend)
+            st = st._replace(clf=jax.tree.map(
+                lambda full, b: full.at[w_sel].set(b), st.clf, clf_b))
 
-        dmax = jnp.max(jnp.where(valid_lb, t_done, -jnp.inf), axis=0)
-        dmin = jnp.min(jnp.where(valid_lb, t_done, jnp.inf), axis=0)
-        has_req = jnp.isfinite(dmax)
-        stall = jnp.where(has_req & slot_ok, dmax - dmin, 0.0)
-        metrics = dict(st.metrics)
-        metrics["stall_cycles"] = (metrics["stall_cycles"]
-                                   + _fixed_order_sum(stall))
-        st = st._replace(metrics=metrics)
+        with jax.named_scope("timing_pass"):
+            st, an, t_done = _timing_pass(st, an, recs, prm, scan_backend)
 
-        w_ok = jnp.where(slot_ok, w_sel, n_warps)    # OOB -> dropped
-        gap = compute_gap if jnp.ndim(compute_gap) == 0 \
-            else compute_gap[i_sel]
-        ready = ready.at[w_ok].set(
-            jnp.where(has_req, dmax + gap, t0 + gap),
-            mode="drop")
-        ptr = ptr.at[w_ok].add(1, mode="drop")
-        # Fig 4 snapshot: sampled ratio after each serviced instruction
-        ratio_t = ratio_t.at[i_sel, w_ok].set(st.clf.ratio[w_sel],
-                                              mode="drop")
+        with jax.named_scope("wave_commit"):
+            (_, _, valid_lb, byp_lb, use_lb, hit_lb, _, vt_lb, ev_lb) = recs
+            # hoisted write-only bookkeeping: one update per wave instead
+            # of one per lane (integer adds — exact either way)
+            m = st.metrics
+            metrics = dict(m)
+            metrics["l2_accesses"] = m["l2_accesses"] + jnp.sum(
+                use_lb.astype(I32))
+            metrics["l2_hits"] = m["l2_hits"] + jnp.sum(
+                hit_lb.astype(I32))
+            metrics["bypasses"] = m["bypasses"] + jnp.sum(
+                byp_lb.astype(I32))
+            # one-hot over the type bins (victim_type is always a written
+            # label, in range) instead of an [N] scatter-add, which
+            # XLA:CPU serializes per element
+            n_types = m["evictions_by_type"].shape[0]
+            vt_oh = vt_lb.reshape(-1)[:, None] \
+                == jnp.arange(n_types, dtype=I32)[None, :]
+            metrics["evictions_by_type"] = m["evictions_by_type"] \
+                + jnp.sum(jnp.where(
+                    vt_oh, ev_lb.reshape(-1)[:, None].astype(I32), 0),
+                    axis=0)
+            st = st._replace(
+                tot_hits=st.tot_hits.at[w_sel].add(
+                    jnp.sum(hit_lb.astype(I32), axis=0)),
+                tot_acc=st.tot_acc.at[w_sel].add(
+                    jnp.sum(valid_lb.astype(I32), axis=0)),
+                metrics=metrics)
+
+            dmax = jnp.max(jnp.where(valid_lb, t_done, -jnp.inf), axis=0)
+            dmin = jnp.min(jnp.where(valid_lb, t_done, jnp.inf), axis=0)
+            has_req = jnp.isfinite(dmax)
+            stall = jnp.where(has_req & slot_ok, dmax - dmin, 0.0)
+            metrics = dict(st.metrics)
+            metrics["stall_cycles"] = (metrics["stall_cycles"]
+                                       + _fixed_order_sum(stall))
+            st = st._replace(metrics=metrics)
+
+            w_ok = jnp.where(slot_ok, w_sel, n_warps)    # OOB -> dropped
+            gap = compute_gap if jnp.ndim(compute_gap) == 0 \
+                else compute_gap[i_sel]
+            ready = ready.at[w_ok].set(
+                jnp.where(has_req, dmax + gap, t0 + gap),
+                mode="drop")
+            ptr = ptr.at[w_ok].add(1, mode="drop")
+            # Fig 4 snapshot: sampled ratio after each serviced
+            # instruction
+            ratio_t = ratio_t.at[i_sel, w_ok].set(st.clf.ratio[w_sel],
+                                                  mode="drop")
         # pin the loop-carried warp-axis sharding (no-ops unsharded):
         # without the constraint GSPMD may resolve the scattered-into
         # carries to a different layout each iteration
@@ -401,7 +424,7 @@ def simulate_core(trace_lines, trace_pcs, compute_gap, oracle_types,
         _, _, _, ptr, _, k = carry
         return (k < n_waves) & jnp.any(ptr < n_instr)
 
-    (st, _, ready, _, ratio_t, _) = jax.lax.while_loop(
+    (st, _, ready, _, ratio_t, waves) = jax.lax.while_loop(
         wave_pending, wave_step,
         (st0, an0, ready0, ptr0, ratio0, jnp.zeros((), I32)))
 
@@ -413,4 +436,5 @@ def simulate_core(trace_lines, trace_pcs, compute_gap, oracle_types,
     st = st._replace(clf=jax.tree.map(rep, st.clf),
                      tot_hits=rep(st.tot_hits), tot_acc=rep(st.tot_acc))
     return REQ.finalize_outputs(st, rep(ready), rep(ratio_t), compute_gap,
-                                n_instr=n_instr, n_warps=n_warps, prm=prm)
+                                n_instr=n_instr, n_warps=n_warps,
+                                prm=prm), waves

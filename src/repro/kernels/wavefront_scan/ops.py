@@ -217,8 +217,8 @@ def wave_queue_recovery(t_s, bank, use_l2, ch, row, go_dram, byp, hp,
 
     Deliberately NOT jitted here: the wavefront engine inlines it into
     its own jitted wave step (a nested pjit boundary would block XLA
-    fusion with the surrounding pass); standalone callers (tests,
-    benchmarks/roofline.py) wrap it in ``jax.jit`` at the call site.
+    fusion with the surrounding pass); standalone callers (tests) wrap
+    it in ``jax.jit`` at the call site.
     """
     kw = dict(banks=banks, channels=channels, l2_svc=l2_svc,
               l2_lat=l2_lat, occ_rowhit=occ_rowhit,

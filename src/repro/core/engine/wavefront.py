@@ -36,8 +36,8 @@ passes:
      arrival-order service times). The implementation now lives in
      ``repro.kernels.wavefront_scan`` behind a backend gate
      (``scan_backend``): ``"ref"`` is the original unfused multi-pass
-     form, ``"fused"`` a bitwise-identical slot-major reformulation with
-     fast associative scans (the default), ``"pallas"`` a one-pass
+     form, ``"fused"`` a bitwise-identical queue-major reformulation
+     with no per-slot gather (the default), ``"pallas"`` a one-pass
      TPU kernel. The DRAM row-buffer chain links each request to its
      true chronological predecessor in its channel, and the low-priority
      queue's floor folds in the running busy horizon of the wave's

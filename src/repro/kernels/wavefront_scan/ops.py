@@ -7,20 +7,26 @@ Backends:
   * ``"ref"``    — the engine's original unfused multi-pass formulation
     (ref.py): cumsum + ``lax.cummax`` per queue family over [Q, N]
     masks. The unfused side of the in-run perf A/B.
-  * ``"fused"``  — bitwise-identical reformulation on slot-major [N, Q]
-    layout: the same exclusive-prefix-occupancy / running-max recovery,
-    but the pathologically slow XLA:CPU ``cummax`` is replaced by a
-    custom ``lax.associative_scan(jnp.maximum)`` (exactly associative,
-    so bitwise-equal), the prefix-occupancy cumsums by
-    ``associative_scan(jnp.add)`` (exact because service occupancies
-    are integer-valued — see ``_scan_add``), per-slot floors are
-    gathered instead of materializing [Q, N] floor matrices, and the
-    carry update runs as dense masked max reductions sharing one mask
-    per queue family (XLA:CPU serializes scatter-max into a
-    per-element loop). Every intermediate that reaches an output is
-    either the same float operation on the same values as ref.py or an
-    exact re-association, so outputs are bit-for-bit equal — which is
-    what lets the engine default to it under the 1e-6 golden suites.
+  * ``"fused"``  — bitwise-identical reformulation laid out for the
+    TPU's vector unit. Every table is queue-major [Q, N], so the wave's
+    N slots run along the 128-wide lane axis and the Q <= 18 queues
+    along the sublanes (slot-major [N, Q] would fill 6–18 of every 128
+    lanes). The prefix scans are ``lax.cumsum`` / ``lax.cummax`` along
+    the slots, which XLA lowers to a two-level reduce-window scan (128
+    lanes, then the blocks); the three occupancy families share one
+    cumsum. Nothing is gathered per slot: the carry floors are [Q, N]
+    tables built elementwise, each slot's own-queue value is a masked
+    max over the queue axis with the one-hot queue masks, and the DRAM
+    row chain is a "last valid value" scan that carries the
+    predecessor's row itself (not an index to look up). The carry
+    update is masked max reductions over the slot axis. Exact by
+    construction: max is exactly associative, the occupancy prefix
+    sums add integer-valued service times far below 2**24, and selects
+    and masked maxes copy values unchanged — so the outputs equal
+    ref.py bit for bit, which is what lets the engine default to it
+    under the 1e-6 golden suites. (A per-slot gather compiles on the TPU
+    to a custom fusion the compiler's cost model cannot estimate;
+    tests/test_tpu_compile.py pins that the compiled pass holds none.)
   * ``"pallas"`` — one-pass TPU kernel (kernel.py): a single chunked
     sweep with a combined (prefix-occ, running-max, predecessor) carry
     recovers bank, HP and LP service times together. Exact on dyadic
@@ -58,136 +64,142 @@ def resolve_backend(backend: str) -> str:
     return "fused" if backend == "auto" else backend
 
 
-def _scan_max(x):
-    """Inclusive running max along axis 0. Bitwise-equal to
-    ``lax.cummax`` (max is exactly associative and the inputs carry no
-    NaNs) but 9–16x faster on XLA:CPU, where the cummax primitive
-    lowers to a degenerate reduce-window."""
-    return jax.lax.associative_scan(jnp.maximum, x, axis=0)
+def _onehot(q, n_q, on):
+    """bool[n_q, N]: slot j is in queue ``q[j]`` and ``on[j]`` holds."""
+    return (q[None, :] == jnp.arange(n_q, dtype=I32)[:, None]) \
+        & on[None, :]
+
+
+def _pick(mask, x_qn):
+    """Each slot's entry of a [Q, N] array at its own queue: a masked max
+    over the queue axis. A slot lies in at most one queue, so this copies
+    that entry unchanged (-inf where ``mask`` holds for no queue)."""
+    return jnp.max(jnp.where(mask, x_qn, _NEG), axis=0)
+
+
+def _floor(free, last_ts, last_sa, t_s, t_svc, exact):
+    """The carry floor of every (queue, slot) pair, [Q, N] or [Q, 1]."""
+    if exact:
+        return free[:, None]
+    return _ref.carry_floor(free, last_ts, last_sa, t_s, t_svc)
 
 
 def _scan_add(x):
-    """Inclusive prefix sum along axis 0 via ``associative_scan`` —
-    ~4x faster than ``jnp.cumsum`` on XLA:CPU. The tree re-associates
-    the additions, which is exact whenever the summands accumulate
-    without rounding: queue occupancies are integer-valued service
-    times (``l2_svc`` / ``occ_rowhit`` / ``occ_rowmiss``) well below
-    2**24, so every partial sum is an exactly-representable integer
-    and the fused backend stays bitwise-equal to ref.py's sequential
-    ``jnp.cumsum`` on them."""
-    return jax.lax.associative_scan(jnp.add, x, axis=0)
+    """Inclusive prefix sum along the slot axis. Exact in any order of
+    association: the summands are integer-valued service occupancies
+    (``l2_svc``, ``occ_rowhit``, ``occ_rowmiss``) and every partial sum
+    stays far below 2**24."""
+    return jax.lax.cumsum(x, axis=1)
 
 
-def _floor_slot(free, last_ts, last_sa, q, t_s, t_svc, exact):
-    """``ref.carry_floor`` evaluated only at each slot's own queue —
-    an O(N) gather instead of a [Q, N] matrix. Identical elementwise
-    math on identical values, so bitwise-equal where it is consumed."""
-    f = free[q]
-    if exact:
-        return f
-    backlog = f - last_sa[q]
-    interp = jnp.minimum(f, t_svc + backlog)
-    return jnp.where(t_s >= last_ts[q], f, interp)
+def _scan_max(x):
+    """Inclusive running max along the slot axis (max is exactly
+    associative, and no NaN enters)."""
+    return jax.lax.cummax(x, axis=1)
 
 
-def _take_q(x_nq, q):
-    """x[j, q_j] for per-slot queue gather on [N, Q] arrays."""
-    return jnp.take_along_axis(x_nq, q[:, None], axis=1)[:, 0]
+def _shift(x, first):
+    """``x[:, j - 1]`` along the slot axis; ``first`` (a scalar or one
+    value per queue) at j = 0."""
+    head = jnp.broadcast_to(jnp.asarray(first, x.dtype).reshape(-1, 1),
+                            (x.shape[0], 1))
+    return jnp.concatenate([head, x[:, :-1]], axis=1)
+
+
+def _hold_last(valid, x):
+    """Inclusive "last valid value" scan along the slot axis: ``x`` at
+    the last j' <= j where ``valid`` holds. log2(N) rounds of shift and
+    select; the combine only copies values, so it is exact."""
+    n = x.shape[1]
+    d = 1
+    while d < n:
+        x = jnp.where(valid, x, jnp.pad(x[:, :-d], ((0, 0), (d, 0))))
+        valid = valid | jnp.pad(valid[:, :-d], ((0, 0), (d, 0)))
+        d *= 2
+    return x
 
 
 def _fused_core(t_s, bank, use_l2, ch, row, go_dram, byp, hp, carry,
                 *, banks, channels, l2_svc, l2_lat, occ_rowhit,
                 occ_rowmiss, exact):
-    """Slot-major [N, Q] recovery; returns (t_head, t0, row_hit)."""
-    n = t_s.shape[0]
-    slot = jnp.arange(n, dtype=I32)
+    """Queue-major [Q, N] recovery; returns (t_head, t0, row_hit)."""
+    bmask = _onehot(bank, banks, use_l2)
+    cmask = _onehot(ch, channels, go_dram)
+    mask_hp = cmask & hp[None, :]
+    mask_lp = cmask & ~hp[None, :]
+
+    # ---- DRAM row chain: no service time enters it -------------------------
+    # a request's predecessor is the previous request of its channel in
+    # this wave, else the carried open row: hold each channel's last row,
+    # one slot late, so that slot j sees only the slots before it
+    rows = jnp.broadcast_to(row[None, :], cmask.shape)
+    prev_row = _hold_last(_shift(cmask, True),
+                          _shift(rows, carry.cur_row))
+    row_hit = jnp.any(cmask & (prev_row == rows), axis=0)
+    occ = jnp.where(row_hit, occ_rowhit, occ_rowmiss)
+
+    # ---- exclusive prefix occupancy of all three families, one scan --------
+    occ_q = jnp.concatenate([jnp.where(bmask, jnp.float32(l2_svc), 0.0),
+                             jnp.where(mask_hp, occ[None, :], 0.0),
+                             jnp.where(mask_lp, occ[None, :], 0.0)])
+    c_q = _scan_add(occ_q) - occ_q
+    occ_hp = occ_q[banks:banks + channels]
+    c_b, c_hp, c_lp = (c_q[:banks], c_q[banks:banks + channels],
+                       c_q[banks + channels:])
 
     # ---- L2 bank queues ----------------------------------------------------
-    # the DRAM predecessor-chain scan is independent of the bank scan,
-    # so both ride ONE associative scan on a [N, banks+channels] concat
-    # (slot indices stay exact in f32 — they are < 2**24)
-    bmask = (bank[:, None] == jnp.arange(banks, dtype=I32)[None, :]) \
-        & use_l2[:, None]
-    cmask = (ch[:, None] == jnp.arange(channels, dtype=I32)[None, :]) \
-        & go_dram[:, None]
-    occ_b = jnp.where(bmask, jnp.full((n,), l2_svc, F32)[:, None], 0.0)
-    c_b = _scan_add(occ_b) - occ_b
-    u_b = jnp.maximum(t_s, _floor_slot(carry.bank_free, carry.bank_ts,
-                                       carry.bank_ts, bank, t_s, t_s,
-                                       exact))
-    v_b = jnp.where(bmask, u_b[:, None] - c_b, _NEG)
-    chain = jnp.where(cmask, slot[:, None], -1).astype(F32)
-    joint = _scan_max(jnp.concatenate([v_b, chain], axis=1))
-    b_start = c_b + joint[:, :banks]
-    inc = joint[:, banks:].astype(I32)
-    t_head = jnp.where(use_l2, _take_q(b_start, bank), 0.0)
+    f_b = _floor(carry.bank_free, carry.bank_ts, carry.bank_ts, t_s, t_s,
+                 exact)
+    v_b = jnp.where(bmask, jnp.maximum(t_s[None, :], f_b) - c_b, _NEG)
+    b_start = c_b + _scan_max(v_b)
+    t_head = jnp.where(use_l2, _pick(bmask, b_start), 0.0)
 
     # ---- DRAM two-queue FR-FCFS --------------------------------------------
     t_da = jnp.where(byp, t_s, t_head + l2_lat)
-    prev_idx = jnp.concatenate(
-        [jnp.full((1, channels), -1, I32), inc[:-1]], axis=0)
-    prev_slot = _take_q(prev_idx, ch)
-    prev_row = jnp.where(prev_slot >= 0,
-                         jnp.take(row, jnp.maximum(prev_slot, 0)),
-                         carry.cur_row[ch])
-    row_hit = (prev_row == row) & go_dram
-    occ = jnp.where(row_hit, occ_rowhit, occ_rowmiss)
-
-    f_hp = _floor_slot(carry.hp_free, carry.hp_ts, carry.hp_sa, ch,
-                       t_s, t_da, exact)
-    mask_hp = cmask & hp[:, None]
-    occ_hp = jnp.where(mask_hp, occ[:, None], 0.0)
-    c_hp = _scan_add(occ_hp) - occ_hp
-    u_hp = jnp.maximum(t_da, f_hp)
-    v_hp = jnp.where(mask_hp, u_hp[:, None] - c_hp, _NEG)
+    f_hp = _floor(carry.hp_free, carry.hp_ts, carry.hp_sa, t_s, t_da,
+                  exact)
+    v_hp = jnp.where(mask_hp, jnp.maximum(t_da[None, :], f_hp) - c_hp,
+                     _NEG)
     hp_start = c_hp + _scan_max(v_hp)
+    # strict priority: a low-priority request waits for the high queue's
+    # busy horizon at its chronological position
     hp_end = jnp.where(mask_hp, hp_start + occ_hp, _NEG)
-    hp_busy = jnp.concatenate(
-        [jnp.full((1, channels), _NEG), _scan_max(hp_end)[:-1]], axis=0)
+    hp_busy = _shift(_scan_max(hp_end), _NEG)
 
-    f_lp = _floor_slot(carry.lp_free, carry.lp_ts, carry.lp_sa, ch,
-                       t_s, t_da, exact)
-    mask_lp = cmask & ~hp[:, None]
-    occ_lp = jnp.where(mask_lp, occ[:, None], 0.0)
-    c_lp = _scan_add(occ_lp) - occ_lp
-    u_lp = jnp.maximum(t_da, jnp.maximum(
-        f_lp, jnp.maximum(f_hp, _take_q(hp_busy, ch))))
-    v_lp = jnp.where(mask_lp, u_lp[:, None] - c_lp, _NEG)
+    f_lp = _floor(carry.lp_free, carry.lp_ts, carry.lp_sa, t_s, t_da,
+                  exact)
+    u_lp = jnp.maximum(t_da[None, :], jnp.maximum(
+        f_lp, jnp.maximum(f_hp, hp_busy)))
+    v_lp = jnp.where(mask_lp, u_lp - c_lp, _NEG)
     lp_start = c_lp + _scan_max(v_lp)
 
-    t0 = jnp.where(hp, _take_q(hp_start, ch), _take_q(lp_start, ch))
+    t0 = jnp.where(hp, _pick(mask_hp, hp_start), _pick(mask_lp, lp_start))
     return t_head, t0, row_hit
 
 
 def _carry_epilogue(t_s, bank, use_l2, ch, row, go_dram, byp, hp, carry,
                     t_head, t0, row_hit, *, banks, channels, l2_svc,
                     l2_lat, occ_rowhit, occ_rowmiss) -> QueueCarry:
-    """Advance the cross-wave carry from per-slot outputs.
-
-    Dense masked [N, Q] max reductions, sharing one mask per queue
-    family. A scatter-max (`.at[q].max`) would be O(N) on paper but
-    lowers to a serialized per-element loop on XLA:CPU — measured ~3x
-    slower than the dense reduce at N=4096 — while max is
-    order-independent and exact, so both forms are bitwise-equal to
-    ref.py's per-queue reductions. The open-row update recovers each
-    channel's LAST serviced slot as a masked max over slot indices."""
+    """Advance the cross-wave carry from per-slot outputs: masked max
+    reductions over the slot axis of [Q, N] tables, one mask per queue
+    family. Max is order-independent and exact, so they equal ref.py's
+    per-queue reductions bit for bit. The open-row update recovers each
+    channel's last serviced slot as a masked max over slot indices."""
     n = t_s.shape[0]
     slot = jnp.arange(n, dtype=I32)
     t_da = jnp.where(byp, t_s, t_head + l2_lat)
     occ = jnp.where(row_hit, occ_rowhit, occ_rowmiss)
 
-    bm = (bank[:, None] == jnp.arange(banks, dtype=I32)[None, :]) \
-        & use_l2[:, None]
-    cm = (ch[:, None] == jnp.arange(channels, dtype=I32)[None, :]) \
-        & go_dram[:, None]
-    cm_hp = cm & hp[:, None]
-    cm_lp = cm & ~hp[:, None]
+    bm = _onehot(bank, banks, use_l2)
+    cm = _onehot(ch, channels, go_dram)
+    cm_hp = cm & hp[None, :]
+    cm_lp = cm & ~hp[None, :]
 
     def qmax(mask, val, base):
         return jnp.maximum(
-            base, jnp.max(jnp.where(mask, val[:, None], _NEG), axis=0))
+            base, jnp.max(jnp.where(mask, val[None, :], _NEG), axis=1))
 
-    last_idx = jnp.max(jnp.where(cm, slot[:, None], -1), axis=0)
+    last_idx = jnp.max(jnp.where(cm, slot[None, :], -1), axis=1)
     cur_row = jnp.where(last_idx >= 0,
                         jnp.take(row, jnp.maximum(last_idx, 0)),
                         carry.cur_row)

@@ -10,8 +10,8 @@ exact FIFO service times the event engine would produce request by
 request: ``start_j = c_j + max_{i<=j}(max(t_i, floor_i) - c_i)`` with
 ``c`` the exclusive prefix occupancy of the request's queue.
 
-It is the differential oracle for ``ops.py``'s fused slot-major
-formulation (bitwise-identical, fewer/faster scans) and the Pallas
+It is the differential oracle for ``ops.py``'s fused queue-major
+formulation (bitwise-identical, no per-slot gather) and the Pallas
 one-pass kernel (``kernel.py``), and it IS the engine's
 ``scan_backend="ref"`` path — the unfused side of the in-run A/B that
 benchmarks/engine_bench.py gates on.

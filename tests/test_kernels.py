@@ -221,10 +221,10 @@ def _wave_case(rng, n, dyadic=True, empty=False, banks=8, channels=4,
             carry)
 
 
-def _recover(args, backend, exact=False):
+def _recover(args, backend, exact=False, **shape):
     from repro.kernels.wavefront_scan.ops import wave_queue_recovery
     return wave_queue_recovery(*args, exact=exact, backend=backend,
-                               **_WS_KW)
+                               **dict(_WS_KW, **shape))
 
 
 def _assert_wave_equal(a, b, slots_exactly=True, go_dram=None):
@@ -242,27 +242,65 @@ def _assert_wave_equal(a, b, slots_exactly=True, go_dram=None):
                                       err_msg=f"carry field {f}")
 
 
+#: the benchmark cell's wave: 512 warps x 16 lanes over 12 L2 banks and 6
+#: DRAM channels (bench/configs/medic_bfs_64k.json)
+_CELL = dict(n=8192, banks=12, channels=6)
+
+
+def _assert_fused_equal(rng, n, dyadic, warm=True, batch=1, exact=False,
+                        banks=8, channels=4):
+    """fused == ref on ``batch`` fuzzed waves; several waves run as one
+    vmapped batch, as the engine runs its policy batch."""
+    cases = [_wave_case(rng, n, dyadic=dyadic, banks=banks,
+                        channels=channels, warm_carry=warm)
+             for _ in range(batch)]
+    kw = dict(banks=banks, channels=channels)
+    if batch == 1:
+        (args,) = cases
+        _assert_wave_equal(_recover(args, "ref", exact, **kw),
+                           _recover(args, "fused", exact, **kw),
+                           go_dram=args[5])
+        return
+    stacked = jax.tree.map(lambda *x: jnp.stack(x), *cases)
+    out = [jax.vmap(lambda *a, b=b: _recover(a, b, exact, **kw))(*stacked)
+           for b in ("ref", "fused")]
+    for i, args in enumerate(cases):
+        ref, fused = (jax.tree.map(lambda x: x[i], o) for o in out)
+        _assert_wave_equal(ref, fused, go_dram=args[5])
+
+
 @pytest.mark.parametrize("dyadic", [True, False])
-@pytest.mark.parametrize("n", [1, 3, 17, 96, 256, 600])
-def test_wavefront_scan_fused_bitwise(n, dyadic):
-    """The fused slot-major path is bit-for-bit equal to the unfused
+@pytest.mark.parametrize("shape", [
+    *[pytest.param(dict(n=n), id=str(n)) for n in (1, 3, 17, 96, 256, 600)],
+    pytest.param(dict(_CELL, warm=True), id="cell-warm"),
+    pytest.param(dict(_CELL, warm=False), id="cell-cold"),
+    pytest.param(dict(_CELL, warm=True, batch=4), id="cell-warm-vmap4"),
+])
+def test_wavefront_scan_fused_bitwise(shape, dyadic):
+    """The fused queue-major path is bit-for-bit equal to the unfused
     oracle — including on non-dyadic floats (same elementwise ops on the
     same values; max exactly associative; integer-valued cumsums exact),
-    which is what lets the engine default to it under 1e-6 goldens."""
-    rng = np.random.default_rng(n * 2 + dyadic)
-    args = _wave_case(rng, n, dyadic=dyadic)
-    _assert_wave_equal(_recover(args, "ref"), _recover(args, "fused"),
-                       go_dram=args[5])
+    which is what lets the engine default to it under 1e-6 goldens. Up
+    to the benchmark cell's wave (8,192 slots, 12 banks, 6 channels),
+    warm and cold, and vmapped over a 4-policy batch."""
+    rng = np.random.default_rng(shape["n"] * 2 + dyadic)
+    _assert_fused_equal(rng, dyadic=dyadic, **shape)
 
 
-@pytest.mark.parametrize("exact", [True, False])
-def test_wavefront_scan_fused_bitwise_exact_mode(exact):
-    """Both carry-floor modes (plain busy-until vs backlog interp)."""
+@pytest.mark.parametrize("exact,shape", [
+    pytest.param(True, {}, id="True"), pytest.param(False, {}, id="False"),
+    *[pytest.param(exact, dict(_CELL, warm=warm, dyadic=dyadic),
+                   id=f"{exact}-cell-{'warm' if warm else 'cold'}-"
+                      f"{'dyadic' if dyadic else 'nondyadic'}")
+      for exact in (True, False) for warm in (True, False)
+      for dyadic in (True, False)],
+])
+def test_wavefront_scan_fused_bitwise_exact_mode(exact, shape):
+    """Both carry-floor modes (plain busy-until vs backlog interp), up
+    to the benchmark cell's wave."""
     rng = np.random.default_rng(7)
-    args = _wave_case(rng, 64, dyadic=False)
-    _assert_wave_equal(_recover(args, "ref", exact=exact),
-                       _recover(args, "fused", exact=exact),
-                       go_dram=args[5])
+    _assert_fused_equal(rng, exact=exact, **{"n": 64, "dyadic": False,
+                                              **shape})
 
 
 @pytest.mark.parametrize("n", [1, 5, 96, 256, 600, 1024])

@@ -12,6 +12,7 @@ may load the TPU library, and a test file that loads it while being
 imported would break multi-worker collection.
 """
 import os
+import re
 
 import jax
 import numpy as np
@@ -20,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.api import Scenario, registry
 from repro.core.engine import SimParams, _simulate_batch
+from repro.core.engine import wavefront as WAVE
 from repro.kernels.cache_pass import ops as CPASS
 from repro.kernels.wavefront_scan import ops as WSCAN
 from repro.policy import stack_policies
@@ -69,7 +71,7 @@ def _compile(exp, sharding, **engine_kw):
                       stack_policies(exp.policies))
     _, n_warps, lanes = call.shape
     return _simulate_batch.lower(
-        *tr, pa, n_warps=n_warps, lanes=lanes, prm=SimParams(),
+        *tr, pa, n_warps=n_warps, lanes=lanes, prm=exp.prm,
         engine=exp.engine, **engine_kw).compile()
 
 
@@ -103,3 +105,54 @@ def test_auto_backends_hold_no_kernel_the_chip_refuses(
     compiled = _compile(exp, one_chip, scan_backend="auto",
                         cache_backend="auto")
     assert "tpu_custom_call" not in compiled.as_text()
+
+
+def _gathers_in_scope(hlo: str, scope: str):
+    """Element counts of the gathers the compiled module ``hlo`` runs
+    under the named scope ``scope``: each gather fusion (a fusion whose
+    computation holds a gather) and each bare gather whose ``op_name``
+    path has ``scope`` as a component."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and line.strip():
+            cur.append(line.strip())
+    holds = {c for c, ls in comps.items()
+             if any(re.search(r"\bgather\(", l) for l in ls)}
+    under = re.compile(r'op_name="[^"]*(?:^|/)' + scope + r'(?:/|")')
+    out = []
+    for ls in comps.values():
+        for l in ls:
+            op = re.match(r"%\S+ = (\w+)\[([\d,]*)\]\S* (\w[\w-]*)\(", l)
+            if not op or not under.search(l):
+                continue
+            called = re.search(r"calls=%([\w.\-]+)", l)
+            if op.group(3) == "gather" or (
+                    op.group(3) == "fusion" and called
+                    and called.group(1) in holds):
+                out.append(int(np.prod([int(d) for d in op.group(2).split(",")
+                                        if d])))
+    return out
+
+
+def test_timing_pass_gathers_nothing_per_slot(one_chip, no_persistent_cache):
+    """The benchmark cell's sweep (2,048 warps x 4 policies, 12 L2
+    banks, 6 DRAM channels, waves of 512 warps x 16 lanes): the timing
+    pass takes every per-slot value with one-hot queue masks and scans,
+    so its compiled form holds no gather over the wave's slots."""
+    exp = registry.stress(scenarios=("HAMMER2K",)).with_(
+        prm=SimParams(sets=384, ways=16, banks=12, l2_lat=10.0,
+                      dram_channels=6))
+    (call,) = exp.compile().calls
+    _, n_warps, lanes = call.shape
+    slots = WAVE.resolve_wave_size(None, n_warps) * lanes
+    assert (n_warps, lanes, slots) == (2048, 16, 8192)
+    hlo = _compile(exp, one_chip).as_text()
+    assert "timing_pass" in hlo
+    per_slot = [n for n in _gathers_in_scope(hlo, "timing_pass")
+                if n % slots == 0]
+    assert per_slot == [], per_slot

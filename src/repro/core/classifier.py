@@ -43,6 +43,11 @@ windows (e.g. the win-32 fast ladder rung) would otherwise never reach
 closes with NO cache-path sample at all (e.g. a token-less PCAL warp)
 relabels to BALANCED: no evidence reverts to the prior.
 
+The label is decided from the integer counts (``warp_types.classify``),
+never from the float ``ratio``: that quotient is telemetry only (Fig 4),
+since the TPU's float32 division is not correctly rounded and a
+threshold such as 0.8 would flip on it.
+
 Everything is functional and vectorized over warps so both the altitude-A
 simulator and the altitude-B serving pool manager use the same code.
 """
@@ -84,9 +89,9 @@ def min_probe_samples(sampling_interval, probe_interval):
     probe_interval`` cache-path samples for a fully-bypassing warp.
     Shared by ``observe`` and the wavefront engine's fused observe
     variants so the three observe paths cannot desynchronize."""
-    guaranteed = jnp.asarray(sampling_interval, jnp.float32) // jnp.maximum(
-        jnp.asarray(probe_interval, jnp.float32), 1.0)
-    return jnp.clip(guaranteed, 1.0, 8.0)
+    guaranteed = jnp.asarray(sampling_interval).astype(jnp.int32) // \
+        jnp.maximum(jnp.asarray(probe_interval).astype(jnp.int32), 1)
+    return jnp.clip(guaranteed, 1, 8)
 
 
 def observe(state: ClassifierState, warp_id, is_hit, *,
@@ -128,7 +133,7 @@ def observe(state: ClassifierState, warp_id, is_hit, *,
     ratio_now = hits.astype(jnp.float32) / jnp.maximum(sampled, 1)
     min_samples = 8 if probe_interval is None \
         else min_probe_samples(sampling_interval, probe_interval)
-    new_type = WT.classify(ratio_now, sampled,
+    new_type = WT.classify(hits, sampled,
                            mostly_hit_threshold=mostly_hit_threshold,
                            mostly_miss_threshold=mostly_miss_threshold,
                            min_samples=min_samples)
@@ -150,7 +155,7 @@ def force_classify(state: ClassifierState, *, mostly_hit_threshold=0.8,
                    ) -> ClassifierState:
     """Classify immediately from whatever counts exist (end-of-window)."""
     ratio_now = state.hits.astype(jnp.float32) / jnp.maximum(state.sampled, 1)
-    new_type = WT.classify(ratio_now, state.sampled,
+    new_type = WT.classify(state.hits, state.sampled,
                            mostly_hit_threshold=mostly_hit_threshold,
                            mostly_miss_threshold=mostly_miss_threshold,
                            min_samples=min_samples)

@@ -56,7 +56,7 @@ def eaf_index(addr, prm: SimParams):
 
 def bypass_decision_core(warp_type_w, accesses_w, token_w, pc_hits_v,
                          pc_acc_v, pc_req_v, addr, valid, prm: SimParams,
-                         pa: PolicyArrays, oracle_wt, rand_u=None):
+                         pa: PolicyArrays, oracle_wt):
     """The bypass decision on fully-gathered inputs: per-warp classifier
     values AND the request's PC-table counter values. The innermost
     shared form — the cache-pass backends (``repro.kernels.cache_pass``)
@@ -74,15 +74,12 @@ def bypass_decision_core(warp_type_w, accesses_w, token_w, pc_hits_v,
     # ``PolicyArrays.probe_interval`` (0 defers to SimParams).
     pi = POL.probe_interval(pa, prm.probe_interval).astype(I32)
     probe = (accesses_w % pi) == pi - 1
-    # the tie-break draw is pure in ``addr`` — the fused sweep hoists it
-    # out of the lane loop and passes it in precomputed
-    if rand_u is None:
-        rand_u = hash_index(addr, 7, 65536).astype(F32) / 65536.0
     byp = POL.bypass_decision(pa, wtype=wtype, probe=probe,
                               token_bit=token_w,
                               pc_hits=pc_hits_v,
                               pc_acc=pc_acc_v,
-                              pc_req=pc_req_v, rand_u=rand_u)
+                              pc_req=pc_req_v,
+                              rand_h=POL.rand_draw(addr))
     return byp & valid, wtype
 
 

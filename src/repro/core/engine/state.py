@@ -53,6 +53,14 @@ class SimParams:
     e_dram: float = 12.0
     e_static: float = 0.08     # per cycle of makespan
 
+    def __post_init__(self):
+        # a window's sample count is at most its window, and the label
+        # ladder decides exactly up to MAX_SAMPLES (warp_types.ratio_cut)
+        if not 0 < self.sampling_interval <= WT.MAX_SAMPLES:
+            raise ValueError(
+                f"sampling_interval must be in [1, {WT.MAX_SAMPLES}], got "
+                f"{self.sampling_interval!r}")
+
 
 class SimState(NamedTuple):
     tags: jnp.ndarray          # i32[sets, ways] line addr or -1

@@ -14,6 +14,10 @@ type <= MOSTLY_MISS, prioritize iff type >= MOSTLY_HIT).
 """
 from __future__ import annotations
 
+import functools
+import math
+from typing import Tuple
+
 import jax.numpy as jnp
 import numpy as np
 
@@ -29,30 +33,87 @@ TYPE_NAMES = ("all-miss", "mostly-miss", "balanced", "mostly-hit", "all-hit")
 # epsilon so that e.g. 127/128 still counts as mostly-hit, not all-hit
 _EPS = 1e-6
 
+#: the largest sample count the integer ladder decides exactly. A
+#: window's sample count never exceeds its sampling window, so
+#: ``SimParams.sampling_interval`` and ``Policy.reclass_interval`` are
+#: held to it; ``q * hits`` then stays far inside int32.
+MAX_SAMPLES = 1 << 14
 
-def classify(hit_ratio, accesses, *, mostly_hit_threshold: float = 0.8,
-             mostly_miss_threshold: float = 0.2, min_samples: int = 8):
-    """Vectorized hit-ratio -> warp-type. Unsampled warps default BALANCED.
 
-    hit_ratio: f32[...] in [0,1]; accesses: i32[...] sample counts.
+@functools.lru_cache(maxsize=None)
+def ratio_cut(threshold: float) -> Tuple[int, int]:
+    """``(p, q)`` with ``q * h >= p * s`` exactly when
+    ``float32(h) / float32(s) >= float32(threshold)``, correctly rounded,
+    for every ``0 <= h <= s``, ``1 <= s <= MAX_SAMPLES``.
+
+    The float comparison is monotone in the exact ratio ``h / s``, so
+    the ratios that pass are those at or above the smallest passing one;
+    ``p / q`` is that ratio. It is found on the host with numpy's float32
+    division (correctly rounded, as the reference's is), and device
+    decisions then never divide: the TPU's float32 division is not
+    correctly rounded, and a quotient one ulp off flips a threshold.
     """
-    r = hit_ratio
-    t = jnp.full(jnp.shape(r), BALANCED, jnp.int32)
-    t = jnp.where(r <= mostly_miss_threshold, MOSTLY_MISS, t)
-    t = jnp.where(r <= _EPS, ALL_MISS, t)
-    t = jnp.where(r >= mostly_hit_threshold, MOSTLY_HIT, t)
-    t = jnp.where(r >= 1.0 - _EPS, ALL_HIT, t)
-    return jnp.where(accesses >= min_samples, t,
+    t = np.float32(threshold)
+    s = np.arange(1, MAX_SAMPLES + 1, dtype=np.int64)
+    # the first passing numerator of each s lies within 1 of t * s
+    cand = np.clip(np.floor(s * np.float64(t)).astype(np.int64)[:, None]
+                   + np.arange(-2, 3), 0, s[:, None] + 1)
+    ok = (cand > s[:, None]) | (
+        cand.astype(np.float32) / s[:, None].astype(np.float32) >= t)
+    assert ((cand[:, 0] == 0) | (cand[:, 0] > s) | ~ok[:, 0]).all()
+    assert ok[:, -1].all()
+    first = cand[np.arange(len(s)), np.argmax(ok, axis=1)]
+    reach = first <= s
+    if not reach.any():
+        return 1, 0                   # no ratio in [0, 1] passes
+    k = np.argmin(np.where(reach, first / s, np.inf))
+    h, d = int(first[k]), int(s[k])
+    g = math.gcd(h, d)
+    return h // g, d // g
+
+
+def ratio_at_least(hits, samples, threshold: float):
+    """``float32(hits / samples) >= float32(threshold)`` as integers
+    (``samples`` >= 1; ``threshold`` a static Python float)."""
+    p, q = ratio_cut(float(threshold))
+    return q * hits >= p * samples
+
+
+def ratio_at_most(hits, samples, threshold: float):
+    """``float32(hits / samples) <= float32(threshold)`` as integers."""
+    above = np.nextafter(np.float32(threshold), np.float32(np.inf))
+    return ~ratio_at_least(hits, samples, float(above))
+
+
+def classify(hits, samples, *, mostly_hit_threshold: float = 0.8,
+             mostly_miss_threshold: float = 0.2, min_samples=8):
+    """Vectorized window counts -> warp-type. Unsampled warps default
+    BALANCED.
+
+    hits, samples: i32[...] cache-path hits and samples of a window
+    (``hits <= samples <= MAX_SAMPLES``). The type is the float32 ladder
+    of ``_ladder_np`` on ``hits / max(samples, 1)``, decided in integers
+    (``ratio_cut``), so it is the same on every chip.
+    """
+    s = jnp.maximum(samples, 1)
+    t = jnp.full(jnp.shape(hits), BALANCED, jnp.int32)
+    t = jnp.where(ratio_at_most(hits, s, mostly_miss_threshold),
+                  MOSTLY_MISS, t)
+    t = jnp.where(ratio_at_most(hits, s, _EPS), ALL_MISS, t)
+    t = jnp.where(ratio_at_least(hits, s, mostly_hit_threshold),
+                  MOSTLY_HIT, t)
+    t = jnp.where(ratio_at_least(hits, s, 1.0 - _EPS), ALL_HIT, t)
+    return jnp.where(samples >= min_samples, t,
                      jnp.full_like(t, BALANCED))
 
 
 def _ladder_np(hit_ratio, mostly_hit_threshold: float,
                mostly_miss_threshold: float) -> np.ndarray:
     """The ratio->type threshold ladder, numpy-vectorized in float32 —
-    the single numpy-side source of the comparisons ``classify`` makes
-    (weakly typed python-float thresholds compare at the array dtype, so
-    the jnp and numpy forms agree bit-for-bit). ``classify_np`` and
-    ``oracle_type_np`` both call this, so the ladder cannot
+    the host-side form of the comparisons ``classify`` makes in integers
+    (numpy's division is correctly rounded, so the two agree on every
+    ``hits / samples``; tests/test_exact_decisions.py). ``classify_np``
+    and ``oracle_type_np`` both call this, so the ladder cannot
     desynchronize between them."""
     r = np.asarray(hit_ratio, np.float32)
     t = np.full(r.shape, BALANCED, np.int32)

@@ -121,15 +121,13 @@ def _fused_narrow(st: SimState, clf_b0: CLF.ClassifierState, tokens_b,
         valid = (addr >= 0) & slot_ok
         sidx = REQ.set_index(addr, prm)
         erd = REQ.eaf_index(addr, prm)
-        rand_u = REQ.hash_index(addr, 7, 65536).astype(F32) / 65536.0
         t_arr = t0 + lane.astype(F32) * prm.lane_skew
 
         # ---- ①② label select + bypass decision ----------------------------
         pc_vals = pc_tab[pidx]                        # [B, 3] one gather
         byp, wtype = REQ.bypass_decision_core(
             clf_b.warp_type, clf_b.accesses, tokens_b, pc_vals[:, 0],
-            pc_vals[:, 1], pc_vals[:, 2], addr, valid, prm, pa, owt_b,
-            rand_u=rand_u)
+            pc_vals[:, 1], pc_vals[:, 2], addr, valid, prm, pa, owt_b)
         use_l2 = valid & ~byp
 
         # ---- L2 lookup (lane-start rows) -----------------------------------
@@ -258,15 +256,13 @@ def _fused_wide(st: SimState, clf_b0: CLF.ClassifierState, tokens_b,
         valid = (addr >= 0) & slot_ok
         sidx = REQ.set_index(addr, prm)
         erd = REQ.eaf_index(addr, prm)
-        rand_u = REQ.hash_index(addr, 7, 65536).astype(F32) / 65536.0
         t_arr = t0 + lane.astype(F32) * prm.lane_skew
 
         # ---- ①② label select + bypass decision ----------------------------
         pc_vals = base_pc + acc_b
         byp, wtype = REQ.bypass_decision_core(
             clf_b.warp_type, clf_b.accesses, tokens_b, pc_vals[:, 0],
-            pc_vals[:, 1], pc_vals[:, 2], addr, valid, prm, pa, owt_b,
-            rand_u=rand_u)
+            pc_vals[:, 1], pc_vals[:, 2], addr, valid, prm, pa, owt_b)
         use_l2 = valid & ~byp
 
         # ---- L2 lookup: one pointer gather, one row gather -----------------

@@ -78,7 +78,7 @@ def observe_gathered(clf: CLF.ClassifierState, w, is_hit, weight, probed,
     sampled = clf.sampled[w] + probed
     due = accesses >= interval
     ratio_now = hits.astype(jnp.float32) / jnp.maximum(sampled, 1)
-    new_type = WT.classify(ratio_now, sampled,
+    new_type = WT.classify(hits, sampled,
                            mostly_hit_threshold=prm.mostly_hit_threshold,
                            mostly_miss_threshold=prm.mostly_miss_threshold,
                            min_samples=min_samples)
@@ -114,7 +114,7 @@ def observe_vec(clf_b: CLF.ClassifierState, is_hit, weight, probed,
     sampled = clf_b.sampled + probed
     due = accesses >= interval
     ratio_now = hits.astype(jnp.float32) / jnp.maximum(sampled, 1)
-    new_type = WT.classify(ratio_now, sampled,
+    new_type = WT.classify(hits, sampled,
                            mostly_hit_threshold=prm.mostly_hit_threshold,
                            mostly_miss_threshold=prm.mostly_miss_threshold,
                            min_samples=min_samples)

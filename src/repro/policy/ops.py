@@ -7,7 +7,7 @@ trace covers every policy and a stacked ``PolicyArrays`` vmaps cleanly.
 
 Callers supply the raw signals a hardware decision point would see
 (current warp type, PC-table counters, PCAL token bit, a per-address
-uniform variate); the ops own the mechanism semantics.
+hash draw); the ops own the mechanism semantics.
 """
 from __future__ import annotations
 
@@ -34,6 +34,9 @@ DEFAULT_PROBE_INTERVAL = 8
 #: at most once more after bypassing starts, then never again.
 PC_PROBE_INTERVAL = 16
 
+#: the Rand(p) coin draws a 16-bit hash of the request's address
+RAND_RANGE = 1 << 16
+
 
 def hash_index(x, salt, mod):
     """Knuth-style multiplicative hash -> [0, mod). Shared by the
@@ -44,8 +47,21 @@ def hash_index(x, salt, mod):
     return (h % jnp.uint32(mod)).astype(I32)
 
 
+def rand_draw(addr):
+    """The Rand(p) coin's draw for a request: i32 in [0, RAND_RANGE)."""
+    return hash_index(addr, 7, RAND_RANGE)
+
+
+def rand_cut(pa: PolicyArrays):
+    """Rand(p) bypasses iff ``draw < rand_cut``. ``draw / 2**16 < p`` in
+    float32 is exact (both sides are), and so is ``p * 2**16``, so the
+    integer form ``draw < ceil(p * 2**16)`` decides the same on every
+    chip without a division."""
+    return jnp.ceil(pa.rand_p * RAND_RANGE).astype(I32)
+
+
 def bypass_decision(pa: PolicyArrays, *, wtype, probe, token_bit,
-                    pc_hits, pc_acc, pc_req, rand_u):
+                    pc_hits, pc_acc, pc_req, rand_h):
     """② Should this request skip the shared cache?
 
     wtype:     i32[] current warp/sequence type (mechanism "medic")
@@ -53,17 +69,23 @@ def bypass_decision(pa: PolicyArrays, *, wtype, probe, token_bit,
     token_bit: bool[] PCAL token ownership (mechanism "pcal")
     pc_hits/pc_acc: i32[] PC-table cache-path counters (mechanism "pcbyp")
     pc_req:    i32[] PC-table all-request cadence counter (probe clock)
-    rand_u:    f32[] uniform variate in [0,1) (mechanism "rand")
+    rand_h:    i32[] the request's coin draw, ``rand_draw`` (mechanism
+               "rand")
+
+    Every decision is made in integers: no float quotient is compared
+    with a threshold, so the answer is the same on every chip.
     """
     c_none = jnp.zeros(jnp.shape(wtype), bool)
     c_medic = WT.is_bypass_type(wtype) & ~probe
     c_pcal = ~token_bit
-    pc_ratio = pc_hits / jnp.maximum(pc_acc, 1)
     # probe on the Nth request of each cadence window (not the zeroth —
     # `% N == 0` would fire on a fresh entry's very first request)
     pc_probe = (pc_req % PC_PROBE_INTERVAL) == PC_PROBE_INTERVAL - 1
-    c_pcbyp = (pc_acc > 32) & (pc_ratio < 0.25) & ~pc_probe
-    c_rand = rand_u < pa.rand_p
+    # the entry's hit ratio below 0.25, in integers: 0.25 is exact in
+    # float32, so this equals the float32 comparison for every
+    # pc_acc < 2**24 (where the float form's own operands are exact)
+    c_pcbyp = (pc_acc > 32) & (4 * pc_hits < pc_acc) & ~pc_probe
+    c_rand = rand_h < rand_cut(pa)
     cand = jnp.stack([c_none, c_medic, c_pcal, c_pcbyp, c_rand]).astype(F32)
     return jnp.tensordot(pa.bypass_sel, cand, axes=1) > 0.5
 

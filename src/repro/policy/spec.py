@@ -15,6 +15,8 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.core.warp_types import MAX_SAMPLES
+
 F32 = jnp.float32
 
 # mechanism menus — index order is the select-weight order everywhere
@@ -39,7 +41,9 @@ class Policy:
     #   oracle — ground-truth per-phase labels from the trace generator.
     labeling: str = "online"
     # sampling window in accesses; 0 = the SimParams default. A
-    # policy-visible knob so one vmapped sweep can compare windows.
+    # policy-visible knob so one vmapped sweep can compare windows. At
+    # most ``warp_types.MAX_SAMPLES``, the largest window the integer
+    # label ladder decides exactly.
     reclass_interval: int = 0
     # probe cadence in accesses: every ``probe_interval``-th access of a
     # bypassing warp still takes the cache path so the classifier keeps
@@ -57,11 +61,11 @@ class Policy:
             raise ValueError(f"unknown scheduler {self.scheduler!r}")
         if self.labeling not in LABEL_MECHS:
             raise ValueError(f"unknown labeling mechanism {self.labeling!r}")
-        if self.reclass_interval < 0 or \
+        if not 0 <= self.reclass_interval <= MAX_SAMPLES or \
                 self.reclass_interval != int(self.reclass_interval):
             raise ValueError(
-                f"reclass_interval must be a non-negative int, got "
-                f"{self.reclass_interval!r}")
+                f"reclass_interval must be an int in [0, {MAX_SAMPLES}], "
+                f"got {self.reclass_interval!r}")
         if self.probe_interval < 0 or \
                 self.probe_interval != int(self.probe_interval):
             raise ValueError(

@@ -18,7 +18,6 @@ from repro.core import warp_types as WT
 from repro.policy import ops
 from repro.policy.spec import PolicyArrays
 
-F32 = jnp.float32
 I32 = jnp.int32
 
 
@@ -34,7 +33,8 @@ class DecisionTables:
         types = jnp.arange(WT.NUM_TYPES, dtype=I32)
         # signals a host control plane does not have are neutralized:
         # no probe, token held (PCAL never bypasses), empty PC table,
-        # rand_u = 1 (rand never fires).
+        # a draw of RAND_RANGE (rand never fires: the cut is at most
+        # RAND_RANGE).
         byp = ops.bypass_decision(
             pa, wtype=types,
             probe=jnp.zeros((WT.NUM_TYPES,), bool),
@@ -42,7 +42,7 @@ class DecisionTables:
             pc_hits=jnp.zeros((WT.NUM_TYPES,), I32),
             pc_acc=jnp.zeros((WT.NUM_TYPES,), I32),
             pc_req=jnp.zeros((WT.NUM_TYPES,), I32),
-            rand_u=jnp.ones((WT.NUM_TYPES,), F32))
+            rand_h=jnp.full((WT.NUM_TYPES,), ops.RAND_RANGE, I32))
         rank = ops.insertion_rank(
             pa, wtype=types, eaf_bit=jnp.zeros((WT.NUM_TYPES,), bool),
             rrip_max=rrip_max)

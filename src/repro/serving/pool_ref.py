@@ -56,11 +56,11 @@ class DictPoolManager:
         if self.win_acc[slot] >= self.cfg.sampling_interval:
             r = self.win_hits[slot] / max(self.win_acc[slot], 1)
             self.ratio[slot] = r
-            self.seq_type[slot] = int(np.asarray(WT.classify(
-                np.float32(r), np.int32(self.win_acc[slot]),
+            self.seq_type[slot] = WT.classify_np(
+                np.float32(r), self.win_acc[slot],
                 mostly_hit_threshold=self.cfg.mostly_hit_threshold,
                 mostly_miss_threshold=self.cfg.mostly_miss_threshold,
-                min_samples=1)))
+                min_samples=1)
             self.win_hits[slot] = 0
             self.win_acc[slot] = 0
 

@@ -13,15 +13,16 @@ from repro.core import warp_types as WT
 
 def test_classify_boundaries():
     acc = jnp.full((6,), 100, jnp.int32)
-    ratios = jnp.asarray([0.0, 0.1, 0.2, 0.5, 0.85, 1.0])
-    t = WT.classify(ratios, acc)
+    # hit ratios 0.0, 0.1, 0.2, 0.5, 0.85, 1.0 over 100 samples
+    hits = jnp.asarray([0, 10, 20, 50, 85, 100], jnp.int32)
+    t = WT.classify(hits, acc)
     assert list(np.asarray(t)) == [WT.ALL_MISS, WT.MOSTLY_MISS,
                                    WT.MOSTLY_MISS, WT.BALANCED,
                                    WT.MOSTLY_HIT, WT.ALL_HIT]
 
 
 def test_classify_insufficient_samples_defaults_balanced():
-    t = WT.classify(jnp.asarray([1.0]), jnp.asarray([2]), min_samples=8)
+    t = WT.classify(jnp.asarray([2]), jnp.asarray([2]), min_samples=8)
     assert int(t[0]) == WT.BALANCED
 
 
@@ -83,7 +84,9 @@ def test_classifier_counters_invariant(outcomes, interval):
        st.integers(min_value=8, max_value=1000))
 def test_classify_total_and_monotone(ratio, acc):
     """Every ratio maps to exactly one type; type is monotone in ratio."""
-    t1 = int(WT.classify(jnp.float32(ratio), jnp.int32(acc)))
-    t2 = int(WT.classify(jnp.float32(min(ratio + 0.3, 1.0)), jnp.int32(acc)))
+    h1 = round(ratio * acc)
+    h2 = min(h1 + round(0.3 * acc), acc)
+    t1 = int(WT.classify(jnp.int32(h1), jnp.int32(acc)))
+    t2 = int(WT.classify(jnp.int32(h2), jnp.int32(acc)))
     assert 0 <= t1 < WT.NUM_TYPES
     assert t2 >= t1

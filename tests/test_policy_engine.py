@@ -208,5 +208,5 @@ def test_classify_np_matches_jnp():
         hits = int(rng.integers(0, acc + 1))
         r = hits / acc
         a = WT.classify_np(r, acc, min_samples=1)
-        b = int(WT.classify(jnp.float32(r), jnp.int32(acc), min_samples=1))
+        b = int(WT.classify(jnp.int32(hits), jnp.int32(acc), min_samples=1))
         assert a == b, (hits, acc)

@@ -84,6 +84,20 @@ def test_event_engine_compiles_for_v5e(one_chip, no_persistent_cache):
     assert compiled.memory_analysis().temp_size_in_bytes > 0
 
 
+def test_paper_suite_wavefront_compiles_for_v5e(one_chip,
+                                                no_persistent_cache):
+    """The paper suite on the wavefront engine at its default wave (8 of
+    48 warps), on MeDiC's Table 1 GPU: the narrow fused cache pass with
+    every Fig 7 policy's integer decisions, no kernel."""
+    exp = registry.PAPER_FIG7.with_(
+        engine="wavefront",
+        prm=SimParams(sets=384, ways=16, banks=12, l2_lat=10.0,
+                      dram_channels=6))
+    assert WAVE.default_wave_size(48) < CPASS.WIDE_WAVE_MIN_B
+    compiled = _compile(exp, one_chip)
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
 def test_wavefront_fused_compiles_for_v5e(one_chip, no_persistent_cache):
     """The stress path at 2048 warps on the fused backends."""
     exp = registry.stress(scenarios=("HAMMER2K",))

@@ -15,22 +15,25 @@ record tuple the timing pass consumes. Backends:
     sweep picks one of two constructions per wave width (a static,
     shape-level choice — B is fixed per compiled wave step):
 
-      - wide waves (B ≥ 128, where same-set aliasing is dense and the
-        scatter volume dominates): the CHRONOLOGY-POINTER construction.
-        Every slot's post-write row lands in a private row buffer (one
-        dynamic-update-slice per lane), and conflict resolution is an
-        explicit segmented argmax over chronological slot index per
-        touched set: each writing slot scatter-MAXES its chronology
-        index into a per-set pointer table, so after the sweep each
-        set's pointer names exactly the last slot in program order that
-        wrote it; the winning rows are dereferenced once at the end.
-        Three pointer chains ride one fused [2·sets + eaf_bits] table:
-        tags+meta advance only on ``allocate``, RRIP on every
-        ``use_l2`` request (hits rewrite their row), and the EAF write
-        degenerates to the same scatter-max because the generation
-        stamp is monotone nondecreasing. The construction is bitwise
-        because (a) every slot's row is computed from lane-start state
-        — exactly what the ref scatters write — and (b) same-lane
+      - wide waves (B ≥ 128, where same-set aliasing is dense and
+        gathers/scatters over the wave's slots dominate — on a TPU they
+        run element by element): the DENSE LANE-START-TABLE
+        construction. The lane scan carries the state tables themselves
+        and touches them only through one-hot contractions and masked
+        reductions. A read is ``onehot[B, N] @ table`` on the MXU, made
+        bitwise by holding each i32 as its four bytes in bf16 (one
+        nonzero term per output). A write is last-write-wins in slot
+        order, made explicit: per table row, the last writing slot is
+        a masked max over slot index, and its row is selected by a
+        second exact contraction. Two chains share the [sets, 3·ways]
+        tag|meta|RRIP table: tags+meta advance only on ``allocate``,
+        RRIP on every ``use_l2`` request (hits rewrite their row). The
+        EAF write stores one stamp, so "any writer" suffices (a count
+        over a [hi, lo] fold of the filter, ~√eaf_bits one-hots); the
+        PC counters are read as ``pc_onehot @ table`` and advanced by
+        ``pc_onehotᵀ @ deltas``. The construction is bitwise because
+        (a) every slot's row is computed from lane-start state —
+        exactly what the ref scatters write — and (b) same-lane
         same-set allocators share identical lane-start RRIP rows, hence
         the same victim way, so the winning row subsumes the losers'
         single-element writes.
@@ -49,11 +52,7 @@ record tuple the timing pass consumes. Backends:
         XLA fuses it for free, keeping the lane scan's sliced inputs
         down to the address matrix alone.
 
-    Wide waves also sort the wave by PC entry once (slots sharing a PC
-    entry form segments) so each lane's counter reads are exact segment
-    sums off one cumsum and the [pc_entries] tables take a single
-    conflict-free scatter-add at wave end. ``"auto"`` picks this
-    backend on every platform.
+    ``"auto"`` picks this backend on every platform.
   * ``"pallas"`` — lane-chunked TPU kernel (kernel.py): grid over the
     L lanes with the cache state carried in VMEM scratch and all
     gather/scatter replaced by dense one-hot selects/reductions.
@@ -71,6 +70,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import classifier as CLF
 from repro.core.engine import request as REQ
@@ -81,13 +81,15 @@ from repro.policy import PolicyArrays, ops as POL
 
 F32 = jnp.float32
 I32 = jnp.int32
+U32 = jnp.uint32
+BF16 = jnp.bfloat16
 
 BACKENDS = ("auto", "fused", "ref", "pallas")
 
 # Static wave-width threshold between the two fused constructions. Below
 # it the pass is dispatch-bound and ref-shaped scatters are effectively
-# free; above it scatter volume dominates and the chronology-pointer
-# merge + sorted-PC segments pay for their fixed overhead.
+# free; above it scatter volume dominates and the dense tables pay for
+# their fixed one-hot work.
 WIDE_WAVE_MIN_B = 128
 
 
@@ -197,82 +199,99 @@ def _fused_narrow(st: SimState, clf_b0: CLF.ClassifierState, tokens_b,
     return new_st, clf_b, records
 
 
+def _bytes(x):
+    """i32[..., C] -> bf16[..., 4C]: the four bytes of each entry's
+    uint32 bit pattern, byte-major (column ``k·C + c`` holds byte ``k``
+    of entry ``c``). Every byte is exact in bf16, so a contraction with
+    a one-hot operand (one nonzero term per output) is exact on the
+    MXU at its default precision."""
+    u = jax.lax.bitcast_convert_type(x, U32)
+    return jnp.concatenate([(u >> (8 * k)) & 0xFF for k in range(4)],
+                           axis=-1).astype(BF16)
+
+
+def _unbytes(y):
+    """Inverse of ``_bytes``: byte planes (any float dtype holding
+    0..255) -> the i32 entries."""
+    u = y.astype(U32)
+    c = y.shape[-1] // 4
+    v = u[..., :c]
+    for k in range(1, 4):
+        v = v | (u[..., k * c:(k + 1) * c] << (8 * k))
+    return jax.lax.bitcast_convert_type(v, I32)
+
+
+def _pick(onehot, table_bytes):
+    """``table[i]`` for each row's one-hot ``i``, as one contraction:
+    bool[B, N] × bf16[N, 4C] byte planes -> i32[B, C], bitwise."""
+    return _unbytes(jnp.dot(onehot.astype(BF16), table_bytes,
+                            preferred_element_type=F32))
+
+
+def _lww(table_bytes, onehot, writes, new_bytes):
+    """Last-write-wins row writes in slot order, dense: each row ``s``
+    of ``table_bytes`` [N, 4C] takes the ``new_bytes`` [B, 4C] row of
+    the LAST slot ``b`` with ``onehot[b, s] & writes[b]``, and keeps its
+    value where no slot writes it. The winner is a masked max over
+    slot index; its row is selected by one exact contraction."""
+    slot = jnp.arange(onehot.shape[0], dtype=I32)[:, None]
+    last = jnp.max(jnp.where(onehot & writes[:, None], slot, -1), axis=0)
+    won = jnp.einsum("bn,bc->nc", (slot == last[None, :]).astype(BF16),
+                     new_bytes, preferred_element_type=F32)
+    return jnp.where((last >= 0)[:, None], won.astype(BF16), table_bytes)
+
+
 def _fused_wide(st: SimState, clf_b0: CLF.ClassifierState, tokens_b,
                 t0, addr_lb, pc_b, owt_b, slot_ok,
                 prm: SimParams, pa: PolicyArrays) -> tuple:
-    """Wide-wave (B ≥ 128) fused sweep — the chronology-pointer
-    construction (see the module docstring). Per lane: one fused
-    3B-index gather resolves tag/meta, RRIP, and EAF reads through the
-    pointer table; every slot's post-write row lands in a private row
-    buffer via one dynamic-update-slice; and the explicit last-write-
-    wins reduction is a single 3B-element scatter-MAX of chronology
-    indices (segmented argmax over slot order per touched set), with
-    non-writing slots parked one-past-the-end and dropped.
+    """Wide-wave (B ≥ 128) fused sweep — the dense lane-start-table
+    construction (see the module docstring). The lane scan carries the
+    state itself: the [sets, 3·ways] tag|meta|RRIP table as byte
+    planes, the [pc_entries, 3] PC counters, and the EAF stamps folded
+    to [hi, lo]. Every read is a one-hot contraction and every write a
+    masked max-reduction over slots plus a contraction, so no lane
+    step gathers or scatters.
     """
-    lanes, B = addr_lb.shape
+    lanes = addr_lb.shape[0]
     W = prm.ways
     S = prm.sets
     E = prm.pc_entries
-    DROP = 2 * S + prm.eaf_bits                       # park index, dropped
-    slot = jnp.arange(B, dtype=I32)
     obs_consts = _ref.observe_consts(prm, pa)
-    pidx = REQ.pc_index(pc_b, prm)                    # constant across lanes
+    # EAF bit e lives at (e // lo, e % lo) of a [hi, lo] fold, so its
+    # one-hots are [B, hi] and [B, lo] (~√eaf_bits wide each) rather
+    # than [B, eaf_bits]; the pad past eaf_bits is never indexed
+    lo = 1 << (((prm.eaf_bits - 1).bit_length() + 1) // 2)
+    hi = -(-prm.eaf_bits // lo)
 
-    # ---- PC segments: sort the wave by PC entry once -----------------------
-    # slots sharing an entry form runs; per lane, one cumsum over the
-    # sorted deltas + two gathers yield each slot's exact running entry
-    # total (integer adds commute), so counter reads are scatter-free
-    # and the [E] tables take ONE conflict-free scatter-add at wave end.
-    pperm = jnp.argsort(pidx)                         # stable
-    spidx = pidx[pperm]
-    inv = jnp.argsort(pperm)
-    brk = spidx[1:] != spidx[:-1]
-    is_end = jnp.concatenate([brk, jnp.ones((1,), bool)])
-    seg_start = jax.lax.cummax(
-        jnp.where(jnp.concatenate([jnp.ones((1,), bool), brk]), slot, -1))
-    seg_end = jax.lax.cummin(jnp.where(is_end, slot, B), reverse=True)
-    first_seg = seg_start == 0
-    seg_idx = jnp.concatenate([seg_end, jnp.maximum(seg_start - 1, 0)])
-    base_pc = jnp.stack([st.pc_hits[pidx], st.pc_acc[pidx],
-                         st.pc_req[pidx]], axis=1)    # [B, 3]
+    def fold(e):
+        return ((e // lo)[:, None] == jnp.arange(hi, dtype=I32),
+                (e % lo)[:, None] == jnp.arange(lo, dtype=I32))
 
-    # ---- row buffer + chronology-pointer table -----------------------------
-    # buf rows 0..S-1 hold the wave-start [tags | meta | rrip] rows; each
-    # lane's B slots own rows S + lane·B + slot. A set's current row is
-    # buf[pointer]; pointers only ever move FORWARD in chronology, which
-    # is what makes the scatter-max below an exact LWW reduction.
-    buf0 = jnp.concatenate(
-        [jnp.concatenate([st.tags, st.meta_type, st.rrip], axis=1),
-         jnp.zeros((lanes * B, 3 * W), I32)], axis=0)
-    # one fused table: [tag/meta ptrs | rrip ptrs | EAF stamps]. The EAF
-    # chain shares the max-reduction because the generation stamp is
-    # monotone nondecreasing (stored stamps ≤ current gen).
-    mtab0 = jnp.concatenate(
-        [jnp.tile(jnp.arange(S, dtype=I32), 2), st.eaf])
+    # the wave's PC entries are fixed across its lanes
+    pc_oh = REQ.pc_index(pc_b, prm)[:, None] == jnp.arange(E, dtype=I32)
+    # table columns: tags | meta advance only on allocate, RRIP on every
+    # use_l2 request (hits rewrite their row)
+    tm_col = (np.arange(12 * W) % (3 * W)) < 2 * W
 
-    def lane_step(carry, x):
-        buf, mtab, eaf_gen, eaf_ctr, clf_b, acc_b = carry
-        lane, addr = x
+    def lane_step(carry, addr):
+        tab, pc_tab, eaf, eaf_gen, eaf_ctr, clf_b = carry
         valid = (addr >= 0) & slot_ok
-        sidx = REQ.set_index(addr, prm)
-        erd = REQ.eaf_index(addr, prm)
-        t_arr = t0 + lane.astype(F32) * prm.lane_skew
+        set_oh = REQ.set_index(addr, prm)[:, None] == jnp.arange(
+            S, dtype=I32)
 
         # ---- ①② label select + bypass decision ----------------------------
-        pc_vals = base_pc + acc_b
+        pc_vals = _pick(pc_oh, _bytes(pc_tab))
         byp, wtype = REQ.bypass_decision_core(
             clf_b.warp_type, clf_b.accesses, tokens_b, pc_vals[:, 0],
             pc_vals[:, 1], pc_vals[:, 2], addr, valid, prm, pa, owt_b)
         use_l2 = valid & ~byp
 
-        # ---- L2 lookup: one pointer gather, one row gather -----------------
-        rd = mtab[jnp.concatenate([sidx, S + sidx, 2 * S + erd])]
-        rows2 = buf[rd[:2 * B]]                       # [2B, 3W]
-        tset, mrow = rows2[:B, :W], rows2[:B, W:2 * W]
+        # ---- L2 lookup: the lane-start rows, one contraction ---------------
+        rows = _pick(set_oh, tab)                     # [B, 3W]
+        tset, mrow = rows[:, :W], rows[:, W:2 * W]
         is_line = tset == addr[:, None]
         hit = jnp.any(is_line, axis=1) & use_l2
-        rset = rows2[B:, 2 * W:]
-        rset = jnp.where(hit[:, None] & is_line, 0, rset)
+        rset = jnp.where(hit[:, None] & is_line, 0, rows[:, 2 * W:])
 
         # ---- ③ fill + insertion --------------------------------------------
         allocate = use_l2 & ~hit
@@ -280,70 +299,65 @@ def _fused_wide(st: SimState, clf_b0: CLF.ClassifierState, tokens_b,
         rset_aged = rset + jnp.where(allocate, shift, 0)[:, None]
         victim = jnp.argmax(rset_aged, axis=1)
         vict_oh = jnp.arange(W, dtype=I32)[None, :] == victim[:, None]
-        pair = jnp.take_along_axis(                   # evicted tag + its type
-            rows2[:B, :2 * W],
-            jnp.stack([victim, W + victim], axis=1), axis=1)
-        evicted, victim_type = pair[:, 0], pair[:, 1]
-        ebit = rd[2 * B:] == eaf_gen
+        evicted = jnp.sum(jnp.where(vict_oh, tset, 0), axis=1)
+        victim_type = jnp.sum(jnp.where(vict_oh, mrow, 0), axis=1)
+        hi_oh, lo_oh = fold(REQ.eaf_index(addr, prm))
+        ebit = jnp.any(lo_oh & (jnp.dot(
+            hi_oh.astype(BF16), (eaf == eaf_gen).astype(BF16),
+            preferred_element_type=F32) > 0), axis=1)
         rank = POL.insertion_rank(pa, wtype=wtype, eaf_bit=ebit,
                                   rrip_max=prm.rrip_max)
 
-        # ---- private row buffer + explicit LWW pointer reduction -----------
-        new_row = jnp.concatenate(
+        # ---- explicit LWW writes in slot order -----------------------------
+        new_row = _bytes(jnp.concatenate(
             [jnp.where(vict_oh, addr[:, None], tset),
              jnp.where(vict_oh, wtype[:, None], mrow),
              jnp.where(allocate[:, None],
                        jnp.where(vict_oh, rank[:, None], rset_aged),
-                       rset)], axis=1)
-        base = S + lane * B
-        buf = jax.lax.dynamic_update_slice(buf, new_row, (base, 0))
+                       rset)], axis=1))
+        tab = jnp.where(tm_col, _lww(tab, set_oh, allocate, new_row),
+                        _lww(tab, set_oh, use_l2, new_row))
+        # every EAF write stores the same stamp, so any writer sets it
         ev_valid = allocate & (evicted >= 0)
-        chrono = base + slot                          # strictly slot-ordered
-        wr_at = jnp.concatenate(
-            [jnp.where(allocate, sidx, DROP),
-             jnp.where(use_l2, S + sidx, DROP),
-             jnp.where(ev_valid, 2 * S + REQ.eaf_index(evicted, prm),
-                       DROP)])
-        wr_val = jnp.concatenate(
-            [chrono, chrono, jnp.broadcast_to(eaf_gen, (B,))])
-        mtab = mtab.at[wr_at].max(wr_val, mode="drop")
+        hi_oh, lo_oh = fold(REQ.eaf_index(evicted, prm))
+        n_set = jnp.einsum("bh,bl->hl",
+                           (hi_oh & ev_valid[:, None]).astype(BF16),
+                           lo_oh.astype(BF16), preferred_element_type=F32)
+        eaf = jnp.where(n_set > 0, eaf_gen, eaf)
 
-        # ---- ① classifier + PC segments + EAF counter ----------------------
+        # ---- ① classifier + PC table + EAF counter -------------------------
         clf_b = _ref.observe_vec(clf_b, hit, valid.astype(I32),
                                  use_l2.astype(I32), prm, pa,
                                  consts=obs_consts)
-        delta = jnp.stack([(hit & use_l2), use_l2, valid, ev_valid],
-                          axis=1).astype(I32)
-        csum = jnp.cumsum(delta[pperm], axis=0)
-        g = csum[seg_idx]                             # [2B, 4] seg ends/starts
-        tot = g[:B] - jnp.where(first_seg[:, None], 0, g[B:])
-        acc_b = acc_b + tot[inv, :3]
-        n_ev = csum[B - 1, 3]
-        eaf_ctr = eaf_ctr + n_ev
+        delta = jnp.stack([(hit & use_l2), use_l2, valid], axis=1)
+        pc_tab = pc_tab + jnp.einsum(
+            "be,bc->ec", pc_oh.astype(BF16), delta.astype(BF16),
+            preferred_element_type=F32).astype(I32)
+        eaf_ctr = eaf_ctr + jnp.sum(ev_valid.astype(I32))
         reset = eaf_ctr >= prm.eaf_capacity
         eaf_gen = jnp.where(reset, eaf_gen + 1, eaf_gen)
         eaf_ctr = jnp.where(reset, 0, eaf_ctr)
 
         hp = POL.is_high_priority(pa, wtype)
-        rec = (t_arr, addr, valid, byp, use_l2, hit, hp,
-               victim_type, ev_valid)
-        return (buf, mtab, eaf_gen, eaf_ctr, clf_b, acc_b), rec
+        rec = (byp, use_l2, hit, hp, victim_type, ev_valid)
+        return (tab, pc_tab, eaf, eaf_gen, eaf_ctr, clf_b), rec
 
-    carry0 = (buf0, mtab0, st.eaf_gen, st.eaf_ctr, clf_b0,
-              jnp.zeros((B, 3), I32))
-    xs = (jnp.arange(lanes, dtype=I32), addr_lb)
-    carry, records = jax.lax.scan(lane_step, carry0, xs)
-    buf, mtab, eaf_gen, eaf_ctr, clf_b, acc_b = carry
-
-    # dereference the winning rows once; write each PC entry's total at
-    # its segment end (conflict-free by construction)
-    fin = buf[mtab[:2 * S]]
-    pc_fin = jnp.stack([st.pc_hits, st.pc_acc, st.pc_req], axis=1).at[
-        jnp.where(is_end, spidx, E)].add(acc_b[pperm], mode="drop")
+    tab0 = _bytes(jnp.concatenate([st.tags, st.meta_type, st.rrip], axis=1))
+    pc_tab0 = jnp.stack([st.pc_hits, st.pc_acc, st.pc_req], axis=1)
+    eaf0 = jnp.pad(st.eaf, (0, hi * lo - prm.eaf_bits)).reshape(hi, lo)
+    carry0 = (tab0, pc_tab0, eaf0, st.eaf_gen, st.eaf_ctr, clf_b0)
+    carry, recs = jax.lax.scan(lane_step, carry0, addr_lb)
+    tab, pc_tab, eaf, eaf_gen, eaf_ctr, clf_b = carry
+    # the records that depend on the lane alone are built outside the scan
+    t_arr = t0 + jnp.arange(lanes, dtype=I32)[:, None].astype(F32) \
+        * prm.lane_skew
+    records = (t_arr, addr_lb, (addr_lb >= 0) & slot_ok) + recs
+    fin = _unbytes(tab)
     new_st = st._replace(
-        tags=fin[:S, :W], rrip=fin[S:, 2 * W:], meta_type=fin[:S, W:2 * W],
-        eaf=mtab[2 * S:], eaf_gen=eaf_gen, eaf_ctr=eaf_ctr,
-        pc_hits=pc_fin[:, 0], pc_acc=pc_fin[:, 1], pc_req=pc_fin[:, 2])
+        tags=fin[:, :W], meta_type=fin[:, W:2 * W], rrip=fin[:, 2 * W:],
+        eaf=eaf.reshape(-1)[:prm.eaf_bits], eaf_gen=eaf_gen,
+        eaf_ctr=eaf_ctr, pc_hits=pc_tab[:, 0], pc_acc=pc_tab[:, 1],
+        pc_req=pc_tab[:, 2])
     return new_st, clf_b, records
 
 

@@ -451,10 +451,12 @@ def _cache_assert_equal(a, b):
 # (sets, wave width B, lanes, addr_hi): sets=1 collapses EVERY request
 # into one set (maximal conflict chains); sets=2 makes every conflict a
 # neighbor of the adjacent set's chain; B >= 128 engages the wide-wave
-# chronology-pointer construction; the last grid is the sparse regime
-# (aliasing only through the hash).
+# dense-table construction, (1, 128, ...) with every request of the wide
+# wave in one set; (512, 200, ...) is the sparse regime (aliasing only
+# through the hash) and (384, 512, 16, ...) the bfs_2k cell's shape.
 _CACHE_GRIDS = [(1, 8, 16, 40), (2, 8, 16, 40), (4, 12, 5, 30),
-                (8, 160, 16, 60), (512, 200, 16, 4000)]
+                (8, 160, 16, 60), (512, 200, 16, 4000), (1, 128, 4, 40),
+                (384, 512, 16, 6000)]
 
 
 @pytest.mark.parametrize("sets,b,lanes,addr_hi", _CACHE_GRIDS)
@@ -475,22 +477,46 @@ def test_cache_pass_fused_bitwise_aliasing_grids(sets, b, lanes, addr_hi):
                             _cache_run(st, args, prm, pa, "fused"))
 
 
+def test_cache_pass_fused_bitwise_wide_extreme_tags():
+    """A wide wave at 16 ways whose tags are all -1 or within 124 of
+    2^31 - 1: the wide construction reads and writes the tag table as
+    byte planes, and these tags set every bit of the top byte (the sign
+    byte of -1 included) as well as the low ones."""
+    from repro.core import baselines as BL
+    from repro.core.engine.state import SimParams
+    from repro.policy import to_arrays
+    prm = SimParams(sets=8, ways=16)
+    base = 2**31 - 1 - (4 * prm.ways + 60)
+    rng = np.random.default_rng(31)
+    for pol in (BL.MEDIC, BL.PCAL):
+        pa = to_arrays(pol)
+        st, args = _cache_case(rng, 320, 160, 8, prm, pa, addr_hi=60)
+        st = st._replace(tags=jnp.where(st.tags >= 0, st.tags + base, -1))
+        addr_lb = args[3]
+        args = args[:3] + (jnp.where(addr_lb >= 0, addr_lb + base, -1),) \
+            + args[4:]
+        ref = _cache_run(st, args, prm, pa, "ref")
+        assert int(jnp.max(ref[0].tags)) > 2**31 - 200
+        _cache_assert_equal(ref, _cache_run(st, args, prm, pa, "fused"))
+
+
 def test_cache_pass_fused_bitwise_empty_wave():
     """No valid slot: the pass must be a state no-op, bitwise, in both
-    backends (what makes the engine's dead tail waves free)."""
+    backends and in both fused constructions (what makes the engine's
+    dead tail waves free)."""
     from repro.core import baselines as BL
     from repro.core.engine.state import SimParams
     from repro.policy import to_arrays
     prm = SimParams(sets=8)
     pa = to_arrays(BL.MEDIC)
     rng = np.random.default_rng(5)
-    st, args = _cache_case(rng, 16, 6, 8, prm, pa, empty=True)
-    ref = _cache_run(st, args, prm, pa, "ref")
-    _cache_assert_equal(ref, _cache_run(st, args, prm, pa, "fused"))
-    np.testing.assert_array_equal(np.asarray(ref[0].tags),
-                                  np.asarray(st.tags))
-    np.testing.assert_array_equal(np.asarray(ref[0].pc_req),
-                                  np.asarray(st.pc_req))
+    for b in (6, 160):                                # narrow, wide
+        st, args = _cache_case(rng, 2 * b + 4, b, 8, prm, pa, empty=True)
+        ref = _cache_run(st, args, prm, pa, "ref")
+        _cache_assert_equal(ref, _cache_run(st, args, prm, pa, "fused"))
+        for k in ("tags", "rrip", "meta_type", "eaf", "pc_req"):
+            np.testing.assert_array_equal(np.asarray(getattr(ref[0], k)),
+                                          np.asarray(getattr(st, k)))
 
 
 def test_cache_pass_pallas_interpret_tiny():

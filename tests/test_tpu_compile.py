@@ -121,11 +121,14 @@ def test_auto_backends_hold_no_kernel_the_chip_refuses(
     assert "tpu_custom_call" not in compiled.as_text()
 
 
-def _gathers_in_scope(hlo: str, scope: str):
-    """Element counts of the gathers the compiled module ``hlo`` runs
-    under the named scope ``scope``: each gather fusion (a fusion whose
-    computation holds a gather) and each bare gather whose ``op_name``
-    path has ``scope`` as a component."""
+def _ops_in_scope(hlo: str, scope: str, kind: str = "gather"):
+    """Element counts of the ``kind`` ops (``gather`` or ``scatter``)
+    the compiled module ``hlo`` runs under the named scope ``scope``:
+    each such op, and each fusion whose computation holds one, that
+    has ``scope`` as a component of its ``op_name`` path or sits in a
+    computation a scoped op calls (a loop body, say). The TPU compiler
+    drops the metadata of scatter fusions, so inside a scoped loop they
+    are found the second way."""
     comps, cur = {}, None
     for line in hlo.splitlines():
         head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
@@ -136,37 +139,111 @@ def _gathers_in_scope(hlo: str, scope: str):
         elif cur is not None and line.strip():
             cur.append(line.strip())
     holds = {c for c, ls in comps.items()
-             if any(re.search(r"\bgather\(", l) for l in ls)}
+             if any(re.search(rf"\b{kind}\(", l) for l in ls)}
     under = re.compile(r'op_name="[^"]*(?:^|/)' + scope + r'(?:/|")')
+    callee = re.compile(r"(?:calls|body|condition|to_apply)=%([\w.\-]+)")
+    inside, todo = set(), [c for ls in comps.values() for l in ls
+                           if under.search(l) for c in callee.findall(l)]
+    while todo:
+        c = todo.pop()
+        if c in comps and c not in inside:
+            inside.add(c)
+            todo += [x for l in comps[c] for x in callee.findall(l)]
     out = []
-    for ls in comps.values():
+    for name, ls in comps.items():
         for l in ls:
-            op = re.match(r"%\S+ = (\w+)\[([\d,]*)\]\S* (\w[\w-]*)\(", l)
-            if not op or not under.search(l):
+            op = re.match(r"%\S+ = (.+?) (\w[\w-]*)\(", l)
+            if not op or not (name in inside or under.search(l)):
                 continue
             called = re.search(r"calls=%([\w.\-]+)", l)
-            if op.group(3) == "gather" or (
-                    op.group(3) == "fusion" and called
+            if op.group(2) == kind or (
+                    op.group(2) == "fusion" and called
                     and called.group(1) in holds):
-                out.append(int(np.prod([int(d) for d in op.group(2).split(",")
-                                        if d])))
+                dims = re.search(r"\w+\[([\d,]*)\]", op.group(1))
+                out.append(int(np.prod([int(d) for d in
+                                        dims.group(1).split(",") if d])))
     return out
 
 
-def test_timing_pass_gathers_nothing_per_slot(one_chip, no_persistent_cache):
+def test_timing_pass_gathers_nothing_per_slot(bfs_2k_hlo):
     """The benchmark cell's sweep (2,048 warps x 4 policies, 12 L2
     banks, 6 DRAM channels, waves of 512 warps x 16 lanes): the timing
     pass takes every per-slot value with one-hot queue masks and scans,
     so its compiled form holds no gather over the wave's slots."""
+    hlo, slots = bfs_2k_hlo
+    assert "timing_pass" in hlo
+    per_slot = [n for n in _ops_in_scope(hlo, "timing_pass")
+                if n % slots == 0]
+    assert per_slot == [], per_slot
+
+
+@pytest.fixture(scope="module")
+def bfs_2k_hlo(one_chip, no_persistent_cache):
+    """The benchmark cell's sweep compiled for the described chip:
+    HAMMER2K's 2,048 warps x 4 policies on MeDiC's Table 1 GPU (384 sets
+    x 16 ways, 12 L2 banks, 6 DRAM channels), waves of 512 warps x 16
+    lanes."""
     exp = registry.stress(scenarios=("HAMMER2K",)).with_(
         prm=SimParams(sets=384, ways=16, banks=12, l2_lat=10.0,
                       dram_channels=6))
     (call,) = exp.compile().calls
     _, n_warps, lanes = call.shape
-    slots = WAVE.resolve_wave_size(None, n_warps) * lanes
-    assert (n_warps, lanes, slots) == (2048, 16, 8192)
-    hlo = _compile(exp, one_chip).as_text()
-    assert "timing_pass" in hlo
-    per_slot = [n for n in _gathers_in_scope(hlo, "timing_pass")
-                if n % slots == 0]
-    assert per_slot == [], per_slot
+    B = WAVE.resolve_wave_size(None, n_warps)
+    assert (n_warps, lanes, B) == (2048, 16, 512)
+    return _compile(exp, one_chip).as_text(), B * lanes
+
+
+def test_wide_cache_pass_gathers_no_rows(bfs_2k_hlo):
+    """The same sweep's wide cache pass (waves of 512 >= the wide
+    threshold) reads the set table, the PC counters and the EAF only by
+    one-hot contractions and writes them by masked reductions, so its
+    compiled form, lane loop included, holds no gather and no scatter:
+    no set-indexed row gather, no chronology-pointer scatter."""
+    hlo, _ = bfs_2k_hlo
+    assert 512 >= CPASS.WIDE_WAVE_MIN_B
+    assert "cache_pass/while" in hlo
+    for kind in ("gather", "scatter"):
+        found = _ops_in_scope(hlo, "cache_pass", kind)
+        assert found == [], (kind, found)
+
+
+def test_wide_cache_pass_compiles_at_64k_warp_width(one_chip,
+                                                    no_persistent_cache):
+    """The widest wave the repo runs, WIDE64K's (65,536 warps, waves of
+    16,384 x 16 lanes, default SimParams), through the wide cache pass
+    alone: its [B, sets] one-hot tables still compile for one v5e, and
+    without a gather or a scatter. Shapes only: no 64k-warp trace is
+    generated."""
+    from repro.core import baselines as BL
+    from repro.core.engine.state import init_state
+    from repro.core.tracegen.stress import SHARD_STRESS_SPECS
+    from repro.policy import ops as POL, to_arrays
+
+    spec = SHARD_STRESS_SPECS["WIDE64K"]
+    n_warps, lanes = spec.n_warps, spec.lines_per_instr
+    B = WAVE.resolve_wave_size(None, n_warps)
+    assert (n_warps, lanes, B) == (65536, 16, 16384)
+    prm = SimParams()
+    pa = to_arrays(BL.MEDIC)
+    shape = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,  # noqa: E731
+                                           sharding=one_chip)
+    per_slot = lambda dtype: shape(  # noqa: E731
+        jax.ShapeDtypeStruct((B,), dtype))
+    st = jax.eval_shape(lambda: init_state(n_warps, prm))
+    tokens = jax.eval_shape(lambda: POL.pcal_tokens(pa, n_warps))
+    args = (jax.tree.map(shape, st),
+            jax.tree.map(lambda a: per_slot(a.dtype), st.clf),
+            per_slot(tokens.dtype), per_slot(np.float32),
+            shape(jax.ShapeDtypeStruct((lanes, B), np.int32)),
+            per_slot(np.int32), per_slot(np.int32), per_slot(np.bool_),
+            jax.tree.map(lambda a: shape(np.asarray(a)), pa))
+
+    def cache_pass(st, clf_b0, tokens_b, t0, addr_lb, pc_b, owt_b,
+                   slot_ok, pa):
+        return CPASS.wave_cache_pass(st, clf_b0, tokens_b, t0, addr_lb,
+                                     pc_b, owt_b, slot_ok, prm, pa,
+                                     backend="fused")
+
+    # the chip's compiler refuses a program that does not fit
+    hlo = jax.jit(cache_pass).lower(*args).compile().as_text()
+    assert " gather(" not in hlo and " scatter(" not in hlo
